@@ -187,8 +187,8 @@ def sweep(workloads: list[Workload], mode: str | None = None,
 
     Workloads are grouped by engine configuration (pool count) and shape
     bucket; each group executes through ``SimEngine.run_grid``, which
-    flattens the grid into device-sharded lanes (``shard_map``/``pmap``
-    across all local devices; the nested-vmap call on one device) — so
+    flattens the grid into device-sharded lanes (``shard_map`` across
+    all local devices; the nested-vmap call on one device) — so
     every grid benchmark gains multi-device execution with no changes.
     The routing policy defaults to the suite-wide ``--routing`` choice.
     """

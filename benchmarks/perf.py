@@ -7,7 +7,7 @@
                                              [--grids a,b,...]
                                              [--kernel lax|pallas]
                                              [--chunk K] [--canon]
-                                             [--cache DIR] [--profile DIR]
+                                             [--profile DIR]
 
 Runs the canonical grids (strategy / pattern / fault sweeps on the paper
 machine) through a **fresh** ``SimEngine`` each — so compile time is
@@ -42,9 +42,9 @@ history) is missing or corrupt (validated *before* any measurement).
 Engine knobs under measurement: ``--arb`` / ``--kernel`` (Pallas
 arbitration / fused route+arbitrate megakernel), ``--chunk K``
 (early-exit granularity of the cycle loop), ``--canon`` (pow2 batch-axis
-canonicalization; its compile-key hit rate lands in the snapshot), and
-``--cache DIR`` (persistent XLA compile cache — repeat-process wall time
-is the metric it moves; also reachable via ``REPRO_COMPILE_CACHE``).
+canonicalization; its compile-key hit rate lands in the snapshot).  The
+persistent XLA compile cache is always on: ``JAX_COMPILATION_CACHE_DIR``
+when set, else ``<repo>/.jax_cache`` (see ``repro.core.engine.cache``).
 ``--profile DIR`` runs one extra, horizon-clamped dispatch per grid
 under a ``jax.profiler`` trace inside an obs trace dir (timing itself is
 never profiled), so ``repro.obs.report`` renders per-grid device
@@ -367,9 +367,6 @@ def main(argv=None) -> int:
     p.add_argument("--canon", action="store_true",
                    help="pow2-canonicalize batch-axis lengths (compile "
                         "sharing across nearby grid sizes)")
-    p.add_argument("--cache", default=None, metavar="DIR",
-                   help="persistent XLA compile cache directory (also: "
-                        "REPRO_COMPILE_CACHE env)")
     p.add_argument("--profile", default=None, metavar="DIR",
                    help="obs trace dir: wrap each grid in a jax.profiler "
                         "trace (<DIR>/xprof/<grid>/) + span/metric events "
@@ -390,8 +387,7 @@ def main(argv=None) -> int:
     unknown = set(grids or []) - set(GRIDS)
     if unknown:
         p.error(f"unknown grids {sorted(unknown)}; have {sorted(GRIDS)}")
-    if args.cache:
-        enable_persistent_cache(args.cache)
+    enable_persistent_cache()
 
     base = None
     base_label = args.compare

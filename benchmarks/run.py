@@ -27,6 +27,8 @@ and scheduler events land in ``DIR/events.jsonl`` next to the run
 manifest, a telemetry-enabled probe grid records per-link utilization
 series, and the fleet report (``DIR/report/report.md`` + CSVs) is
 rendered at the end.
+The persistent XLA compile cache is on for every run:
+``JAX_COMPILATION_CACHE_DIR`` when set, else ``<repo>/.jax_cache``.
 """
 
 import argparse
@@ -78,6 +80,9 @@ def main(argv=None):
     if args.quick and args.full:
         p.error("--quick and --full are mutually exclusive")
     quick = not args.full
+
+    from repro.core.engine import enable_persistent_cache
+    enable_persistent_cache()
 
     from benchmarks import common
     common.NUM_SEEDS = max(1, args.seeds)

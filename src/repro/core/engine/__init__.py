@@ -4,7 +4,7 @@ Public surface:
 
   * :class:`SimEngine` / :func:`get_engine` — compile-once, run-many
     execution with ``run`` / ``run_batch`` / ``run_seeds`` and the
-    device-sharded ``run_grid`` (lane axis over shard_map / pmap / vmap);
+    device-sharded ``run_grid`` (lane axis over shard_map / vmap);
   * :class:`WorkloadTables` / :func:`make_workload_tables` — per-workload
     device data as a padded pytree of jit arguments (packed to
     int8/int16 by bucket-derived bounds; see :mod:`.packing`);
@@ -18,7 +18,7 @@ The legacy entry points ``build_simulator`` / ``simulate`` in
 """
 
 from repro.core.engine.arb import arbitrate_lax, make_arbiter
-from repro.core.engine.cache import cache_dir, enable_persistent_cache
+from repro.core.engine.cache import enable_persistent_cache
 from repro.core.engine.packing import pack, pack_dtype
 from repro.core.engine.route_kernel import make_fused_router
 from repro.core.engine.runner import (
@@ -50,7 +50,6 @@ __all__ = [
     "arbitrate_lax",
     "build_static_tables",
     "build_step",
-    "cache_dir",
     "default_lane_backend",
     "enable_persistent_cache",
     "get_engine",

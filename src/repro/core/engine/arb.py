@@ -22,11 +22,12 @@ Two implementations, selected by ``StaticTables.arb``:
     switch's outputs, and heads are switch-major in queue order), so each
     instance loads its (IN*P*V,) slice of requests/keys, builds the
     (heads, OUT) request matrix in registers/VMEM and takes a masked min
-    per output — no scatter at all.  Integer min over unique keys is
-    platform-independent, so the kernel is **bit-exact** against the lax
-    reference (regression-pinned in ``tests/test_arb.py``, interpret
-    mode on CPU CI; compiled on TPU where ``interpret=None`` resolves to
-    False).
+    per output — no scatter at all.  Keys enter as order-preserving int32
+    (:func:`order_keys`; Mosaic has no unsigned reductions).  Integer min
+    over unique keys is platform-independent, so the kernel is
+    **bit-exact** against the lax reference (regression-pinned in
+    ``tests/test_arb.py``, interpret mode on CPU CI; compiled on TPU
+    where ``interpret=None`` resolves to False).
 
 Both backends vmap (pallas_call has a batching rule that prepends grid
 dimensions), so lane-batched grids run unchanged under either.
@@ -60,18 +61,35 @@ def arbitrate_lax(req_out, packed, S: int, OUT: int):
     return won, gcount
 
 
+def order_keys(packed):
+    """uint32 keys -> int32 with the same order (``key ^ 2**31``, bitcast).
+
+    Mosaic reduces signed integers only; the flip maps uint32 order onto
+    int32 order, so an int32 min picks the same winner.
+    """
+    return jax.lax.bitcast_convert_type(packed ^ jnp.uint32(1 << 31), I32)
+
+
+# order_keys(_INVALID): above every real key, which is < 2**32 - 1
+INVALID_KEY = np.int32(np.iinfo(np.int32).max)
+
+
 def _arb_kernel(local_ref, key_ref, won_ref, gcnt_ref, *, OUT: int):
-    """One switch: masked min per output over this switch's queue heads."""
-    lp = local_ref[0]                        # (HS,) local port, -1 = none
-    key = key_ref[0]                         # (HS,) packed uint32, unique
+    """One switch: masked min per output over this switch's queue heads.
+
+    Heads run down the sublanes and outputs along the lanes: per-head
+    values are ``(HS, 1)`` columns, per-output values ``(1, OUT)`` rows.
+    """
+    lp = local_ref[0]                        # (HS, 1) local port, -1 = none
+    key = key_ref[0]                         # (HS, 1) ordered int32, unique
     HS = lp.shape[0]
-    oid = jax.lax.broadcasted_iota(jnp.int32, (HS, OUT), 1)
-    req = lp[:, None] == oid                 # (HS, OUT) request matrix
-    vals = jnp.where(req, key[:, None], _INVALID)
-    grant = vals.min(axis=0)                 # (OUT,) winning key per output
-    won = req & (key[:, None] == grant[None, :])
-    won_ref[0] = won.any(axis=1).astype(I32)
-    gcnt_ref[0] = won.sum(axis=0).astype(I32)
+    oid = jax.lax.broadcasted_iota(I32, (HS, OUT), 1)
+    req = lp == oid                          # (HS, OUT) request matrix
+    vals = jnp.where(req, key, INVALID_KEY)
+    grant = vals.min(axis=0, keepdims=True)  # (1, OUT) winning key
+    won = req & (key == grant)
+    won_ref[0] = won.any(axis=1, keepdims=True).astype(I32)
+    gcnt_ref[0] = won.astype(I32).sum(axis=0, keepdims=True)
 
 
 def make_arbiter(
@@ -96,15 +114,16 @@ def make_arbiter(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     sw = jnp.asarray(np.arange(H) // HS, dtype=I32)  # switch of each head
+    # one program per switch; the unit dims keep every block's two minor
+    # dims equal to the array's, as Mosaic's (8, 128) tiling requires
+    head = pl.BlockSpec((1, HS, 1), lambda s: (s, 0, 0))
     call = pl.pallas_call(
         functools.partial(_arb_kernel, OUT=OUT),
         grid=(S,),
-        in_specs=[pl.BlockSpec((1, HS), lambda s: (s, 0)),
-                  pl.BlockSpec((1, HS), lambda s: (s, 0))],
-        out_specs=[pl.BlockSpec((1, HS), lambda s: (s, 0)),
-                   pl.BlockSpec((1, OUT), lambda s: (s, 0))],
-        out_shape=[jax.ShapeDtypeStruct((S, HS), jnp.int32),
-                   jax.ShapeDtypeStruct((S, OUT), jnp.int32)],
+        in_specs=[head, head],
+        out_specs=[head, pl.BlockSpec((1, 1, OUT), lambda s: (s, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((S, HS, 1), I32),
+                   jax.ShapeDtypeStruct((S, 1, OUT), I32)],
         interpret=interpret,
         name="switch_arbitration",
     )
@@ -114,7 +133,8 @@ def make_arbiter(
         local = jnp.where(
             req_out < S * OUT, req_out - sw * OUT, -1
         ).astype(I32)
-        won2d, g2d = call(local.reshape(S, HS), packed.reshape(S, HS))
-        return won2d.reshape(H).astype(bool), g2d.reshape(S * OUT)
+        won, g = call(local.reshape(S, HS, 1),
+                      order_keys(packed).reshape(S, HS, 1))
+        return won.reshape(H).astype(bool), g.reshape(S * OUT)
 
     return arbiter

@@ -1,73 +1,36 @@
-"""Persistent XLA compilation cache wiring (compile amortization).
+"""Persistent XLA compilation cache placement (compile amortization).
 
 Every engine configuration compiles its cycle loop once per process; for
-grid sweeps driven from short-lived processes (benchmarks, CI smokes,
-fleet workers) that first compile dominates wall time.  Pointing jax's
-persistent compilation cache at a directory makes the *second process*
-start from the serialized executable instead of recompiling:
+grid sweeps driven from short-lived processes (benchmarks, the chip
+smoke run) that first compile dominates wall time.  JAX's persistent
+compilation cache lets a later process start from the serialized
+executable instead of recompiling.  The cache is keyed partly on its
+directory, so the directory must not move between processes:
 
-    REPRO_COMPILE_CACHE=/path/to/cache python -m benchmarks.perf ...
+  * ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads it itself at import;
+    :func:`enable_persistent_cache` leaves JAX's settings alone;
+  * unset — the cache goes to the fixed ``<repo>/.jax_cache``.
 
-or programmatically::
-
-    from repro.core.engine import enable_persistent_cache
-    enable_persistent_cache("/path/to/cache")
-
-:class:`~repro.core.engine.runner.SimEngine` calls
-:func:`enable_persistent_cache` (no arguments — environment-gated) at
-construction, so any engine consumer opts in with the env var alone.
-The thresholds are dropped to zero so even the small single-scenario
-executables are cached: the engine's compiles are keyed on shape
-buckets, so the cache stays small (one entry per bucket, not per
-workload), and lane canonicalization (``SimEngine(canon=True)``) keeps
-nearby grid sizes on the same entries.
+Entry points (``chip_smoke.py``, ``benchmarks/run.py``,
+``benchmarks/perf.py``) call :func:`enable_persistent_cache` once at
+start-up; library code (``SimEngine``) never does.
 """
 
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 import jax
 
-ENV_VAR = "REPRO_COMPILE_CACHE"
-
-_configured: str | None = None
-
-
-def enable_persistent_cache(path: str | os.PathLike | None = None) -> str | None:
-    """Point jax's persistent compilation cache at ``path`` (idempotent).
-
-    ``path=None`` reads the ``REPRO_COMPILE_CACHE`` environment variable
-    and silently no-ops when it is unset — the default-off contract every
-    engine constructor relies on.  Returns the configured directory (or
-    ``None`` when the cache stays off).  Re-pointing an already-configured
-    process at a *different* directory raises: jax's cache config is
-    process-global and executables already serialized to the old
-    directory would silently stop being reused.
-    """
-    global _configured
-    if path is None:
-        path = os.environ.get(ENV_VAR) or None
-    if path is None:
-        return _configured
-    path = str(path)
-    if _configured is not None:
-        if path != _configured:
-            raise ValueError(
-                f"persistent compile cache already configured at "
-                f"{_configured!r}; refusing to re-point it at {path!r} "
-                f"(jax cache config is process-global)"
-            )
-        return _configured
-    jax.config.update("jax_compilation_cache_dir", path)
-    # cache every executable, however small/fast the compile — the engine
-    # keys on shape buckets, so entry count stays bounded by bucket count
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _configured = path
-    return _configured
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = str(Path(__file__).resolve().parents[4] / ".jax_cache")
 
 
-def cache_dir() -> str | None:
-    """The configured persistent-cache directory, or ``None`` when off."""
-    return _configured
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compile cache; return its directory."""
+    env = os.environ.get(ENV_VAR)
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return DEFAULT_DIR

@@ -23,10 +23,13 @@ in HBM (``unaligned``/``is_min``/``legal``/``cost``/``qi_down``/``room``/
 ``cost2``/...).  Here they are all VMEM-resident per program: the only
 ``(H, q*n)`` array that still travels through HBM is the jitter draw,
 which must be generated by the host PRNG stream to stay bit-identical.
-Cross-switch reads (downstream queue lengths, neighbour in-port
-occupancy, coordinates of the routing destination) arrive as whole-array
-inputs (``qlen`` is NQ int32 — a few hundred KB on paper-scale machines,
-comfortably VMEM-sized); everything else is blocked per switch.
+Cross-switch reads are gathered per switch in XLA before the call — the
+routing destination's coordinates per head, the neighbour in-port
+occupancy behind each port, and the ``(P*V, q*n)`` slab of queue
+lengths behind them — because Mosaic has no 1-D gathers; inside the
+kernel a head picks its entry with a one-hot compare.  Everything is
+blocked per switch, with unit dims that keep each block's two minor
+dims equal to the array's (Mosaic's tiling rule).
 
 Off TPU the kernel runs in Pallas interpret mode (CPU CI); on TPU
 ``interpret=None`` resolves to a compiled kernel.  Parity across all
@@ -43,10 +46,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core.engine.arb import INVALID_KEY, order_keys
 from repro.route import get_policy
 
 I32 = jnp.int32
-_INVALID = np.uint32(0xFFFFFFFF)
 
 
 def make_fused_router(st, interpret: bool | None = None) -> Callable:
@@ -60,202 +63,214 @@ def make_fused_router(st, interpret: bool | None = None) -> Callable:
     """
     S, OUT, H, IN = st.S, st.OUT, st.H, st.IN
     QN = st.q * st.n
-    P, V, CAP, NQ = st.P, st.V, st.CAP, st.NQ
-    PEN, m = st.PEN, st.m
+    P, V, CAP = st.P, st.V, st.CAP
+    PV = P * V
+    PEN, m, q = st.PEN, st.m, st.q
     adaptive = get_policy(st.mode).adaptive_deroutes
     if H % S:
         raise ValueError(f"H={H} not switch-major divisible by S={S}")
     HS = H // S
-    SIG = QN * P * V  # (port, pool, VC) signatures — sizes the arr1 histogram
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    # numpy scalar (not a jax array): pallas kernels may only close over
+    # a numpy scalar (not a jax array): pallas kernels may only close over
     # literals — jax-array constants must travel as inputs
     BIGCOST = np.int32(1 << 28)
 
-    # static tables, widened once at build time (they may be int8/int16
-    # packed); all are trace constants shared by every cycle
-    nbr_t = st.nbr.astype(I32)                       # (S, QN)
-    ipnb_t = st.in_port_at_nb.astype(I32)            # (S, QN)
-    pdim_t = st.port_dim.astype(I32)                 # (QN,)
-    pval_t = st.port_val.astype(I32)                 # (QN,)
-    coords_t = st.coords.astype(I32)                 # (S, q)
-    q = coords_t.shape[1]
-    hpool_t = st.h_pool.astype(I32).reshape(S, HS)   # (S, HS)
+    # static per-switch rows, widened once at build time (the tables may
+    # be int8/int16 packed); all are trace constants shared by every cycle
+    coords_np = np.asarray(st.coords, dtype=np.int32)          # (S, q)
+    pdim_np = np.asarray(st.port_dim, dtype=np.int32)          # (QN,)
+    down_np = (np.asarray(st.nbr, dtype=np.int32) * IN
+               + np.asarray(st.in_port_at_nb, dtype=np.int32))  # (S, QN)
+    down_t = jnp.asarray(down_np)
+    cur_d_t = jnp.asarray(coords_np[:, pdim_np].reshape(S, 1, QN))
+    pdim_t = jnp.asarray(pdim_np.reshape(1, QN))
+    pval_t = jnp.asarray(st.port_val, dtype=I32).reshape(1, QN)
+    hpool_t = st.h_pool.astype(I32).reshape(S, HS, 1)
 
     def kernel(exists_ref, at_dst_ref, dof_ref, der_ref, vcn_ref, pool_ref,
-               rdsw_ref, key_ref, jit_ref, nbr_ref, ipnb_ref, lok_ref,
-               busy_ref, ccur_ref, coords_ref, pdim_ref, pval_ref,
-               qlen_ref, pocc_ref, ndead_ref,
+               key_ref, cdst_ref, jit_ref, curd_ref, down_ref, lok_ref,
+               occ_ref, busy_ref, slab_ref, pdim_ref, pval_ref, ndead_ref,
                won_ref, won2_ref, qib_ref, bmin_ref, esc_ref, a1t_ref,
                g1_ref, g2_ref):
-        exists = exists_ref[0] != 0                  # (HS,)
+        # heads run down the sublanes, ports along the lanes: per-head
+        # values are (HS, 1) columns, per-port values (1, QN) rows, and
+        # every gather is a one-hot select over a lane or sublane axis
+        exists = exists_ref[0] != 0                  # (HS, 1)
         at_dst = at_dst_ref[0] != 0
         dof = dof_ref[0]
         der = der_ref[0]
-        vcn = vcn_ref[0]
-        pool = pool_ref[0]
-        rdsw = rdsw_ref[0]
-        packed = key_ref[0]                          # (HS,) uint32, unique
+        kv = pool_ref[0] * V + vcn_ref[0]            # (pool, VC) of next hop
+        key = key_ref[0]                             # ordered int32, unique
+        cdst = cdst_ref[0]                           # (HS, q) dest coords
         jitter = jit_ref[0]                          # (HS, QN)
-        nbr = nbr_ref[0]                             # (QN,)
-        ipnb = ipnb_ref[0]
-        healthy_row = lok_ref[0] != 0
-        busy = busy_ref[0]                           # (OUT,)
-        ccur = ccur_ref[0]                           # (q,)
-        coords = coords_ref[...]                     # (S, q) whole array
-        pdim = pdim_ref[...]
+        cur_d = curd_ref[0]                          # (1, QN)
+        down = down_ref[0]                           # (1, QN) (nbr, in-port)
+        healthy = lok_ref[0] != 0                    # (1, QN)
+        occ_row = occ_ref[0]                         # (1, QN)
+        busy = busy_ref[0]                           # (1, OUT)
+        slab = slab_ref[0]                           # (PV, QN) qlen behind
+        pdim = pdim_ref[...]                         # (1, QN)
         pval = pval_ref[...]
-        qlen = qlen_ref[...]                         # (NQ,) whole array
-        pocc = pocc_ref[...]                         # (S*IN,) whole array
+
+        pcol = jax.lax.broadcasted_iota(I32, (HS, QN), 1)
+        oid = jax.lax.broadcasted_iota(I32, (HS, OUT), 1)
+
+        def pick(onehot, x):
+            """Per-head value of ``x`` at the one-hot lane: (HS, 1)."""
+            return jnp.where(onehot, x, 0).sum(axis=1, keepdims=True)
+
+        def per_kv(rows):
+            """(HS, QN) value ``rows[kv[h]][p]`` from PV rows of (1, QN)."""
+            out = jnp.zeros((HS, QN), I32)
+            for k in range(PV):
+                out = jnp.where(kv == k, rows[k], out)
+            return out
+
+        def first_min(c):
+            """Min cost and its first lane (``jnp.argmin`` tie rule)."""
+            lo = c.min(axis=1, keepdims=True)
+            return lo, jnp.where(c == lo, pcol, QN).min(axis=1, keepdims=True)
+
+        def arbitrate(req):
+            """Masked tie-free min per output: (won (HS, 1), count (1, OUT))."""
+            grant = jnp.where(req, key, INVALID_KEY).min(axis=0,
+                                                         keepdims=True)
+            wonrow = req & (key == grant)
+            return (wonrow.any(axis=1, keepdims=True),
+                    wonrow.astype(I32).sum(axis=0, keepdims=True))
 
         # ---- candidate network ports (all (HS, QN), VMEM-resident) ----
-        cur_d = ccur[pdim]                           # (QN,)
-        dst_d = coords[rdsw][:, pdim]                # (HS, QN)
-        unaligned = cur_d[None, :] != dst_d
-        not_self = (pval != cur_d)[None, :]
-        is_min = (pval[None, :] == dst_d) & unaligned
-        healthy = jnp.broadcast_to(healthy_row[None, :], (HS, QN))
-        qi_down = ((nbr[None, :] * IN + ipnb[None, :]) * P
-                   + pool[:, None]) * V + vcn[:, None]
-        room = qlen[qi_down] < CAP
-        occ_row = pocc[nbr * IN + ipnb]              # (QN,)
-        avail_net = (busy[:QN] < 2)[None, :]
+        dst_d = jnp.zeros((HS, QN), I32)
+        for d in range(q):
+            dst_d = jnp.where(pdim == d, cdst[:, d:d + 1], dst_d)
+        unaligned = cur_d != dst_d
+        not_self = pval != cur_d
+        is_min = (pval == dst_d) & unaligned
+        qi_down = down * PV + kv
+        qlen_down = per_kv([slab[k:k + 1, :] for k in range(PV)])
+        room = qlen_down < CAP
+        avail_net = busy[:, :QN] < 2
         if adaptive:
-            reserve = jnp.minimum(ndead_ref[0], max(m - 1, 0))
+            reserve = jnp.minimum(ndead_ref[...], max(m - 1, 0))
             base = unaligned & not_self & healthy
             escalate = (
                 ~(is_min & healthy).any(axis=1, keepdims=True)
-                & base & (der[:, None] > 0)
+                & base & (der > 0)
             )
             legal = (
-                (base & (is_min | (der[:, None] > reserve)) | escalate)
+                (base & (is_min | (der > reserve)) | escalate)
                 & room & avail_net
             )
         else:
             is_min_h = is_min & healthy
             escalate = (
                 ~is_min_h.any(axis=1, keepdims=True)
-                & unaligned & not_self & healthy & (der[:, None] > 0)
+                & unaligned & not_self & healthy & (der > 0)
             )
             legal = (is_min_h | escalate) & room & avail_net
-        cost = occ_row[None, :] * 8 + PEN * (~is_min) + jitter
+        cost = occ_row * 8 + jnp.where(is_min, 0, PEN) + jitter
         cost = jnp.where(legal, cost, BIGCOST)
-        best = jnp.argmin(cost, axis=1).astype(I32)
-        best_cost = jnp.take_along_axis(cost, best[:, None], 1)[:, 0]
+        best_cost, best = first_min(cost)
         has_port = best_cost < BIGCOST
-        best_min1 = jnp.take_along_axis(is_min, best[:, None], 1)[:, 0]
+        at_best = pcol == best
+        best_min1 = (at_best & is_min).any(axis=1, keepdims=True)
         out_port = jnp.where(at_dst, QN + dof, best)
         requesting = exists & (at_dst | has_port)
-        requesting = requesting & (busy[out_port] < 2)
+        requesting = requesting & (pick(oid == out_port, busy) < 2)
 
         # ---- round 1: masked tie-free min per output ----
-        oid = jax.lax.broadcasted_iota(I32, (HS, OUT), 1)
-        req1 = requesting[:, None] & (out_port[:, None] == oid)
-        grant1 = jnp.where(req1, packed[:, None], _INVALID).min(axis=0)
-        won1row = req1 & (packed[:, None] == grant1[None, :])
-        won1 = won1row.any(axis=1)
-        g1 = won1row.sum(axis=0).astype(I32)         # (OUT,)
-        qi_best1 = jnp.take_along_axis(qi_down, best[:, None], 1)[:, 0]
+        won1, g1 = arbitrate(requesting & (oid == out_port))
+        qi_best1 = pick(at_best, qi_down)
 
         # ---- round-1 arrivals, switch-locally ----
         # the queue behind (port p, pool, vc) is fed only by this switch,
         # so arr1 is a histogram of net winners over (port, pool, VC)
         # signatures — equal to the global scatter's values at every
         # queue this switch's heads can observe
-        w1net = won1 & ~at_dst
-        sig = (best * P + pool) * V + vcn            # (HS,) winner signature
-        sid = jax.lax.broadcasted_iota(I32, (SIG, HS), 0)
-        counts = ((sig[None, :] == sid) & w1net[None, :]).sum(axis=1)
-        pcol = jax.lax.broadcasted_iota(I32, (HS, QN), 1)
-        want = (pcol * P + pool[:, None]) * V + vcn[:, None]
-        arr1 = counts[want].astype(I32)              # (HS, QN)
+        w1net = at_best & (won1 & ~at_dst)
+        arr1 = per_kv([(w1net & (kv == k)).astype(I32).sum(axis=0,
+                                                           keepdims=True)
+                       for k in range(PV)])
 
         # ---- round 2: losers re-route to ports with tokens + room ----
-        tokens = (2 - busy) - g1
+        tokens = (2 - busy) - g1                     # (1, OUT)
         loser = requesting & ~won1
-        tok_net = (tokens[:QN] > 0)[None, :]
-        room2 = qlen[qi_down] + arr1 < CAP
+        tok_net = tokens[:, :QN] > 0
+        room2 = qlen_down + arr1 < CAP
         cost2 = jnp.where(legal & tok_net & room2, cost, BIGCOST)
-        best2 = jnp.argmin(cost2, axis=1).astype(I32)
-        has2 = jnp.take_along_axis(cost2, best2[:, None], 1)[:, 0] < BIGCOST
-        ej_ok = at_dst & (tokens[QN + dof] > 0)
+        best2_cost, best2 = first_min(cost2)
+        has2 = best2_cost < BIGCOST
+        ej_ok = at_dst & (pick(oid == QN + dof, tokens) > 0)
         out2 = jnp.where(at_dst, QN + dof, best2)
-        req2v = loser & jnp.where(at_dst, ej_ok, has2)
-        req2 = req2v[:, None] & (out2[:, None] == oid)
-        grant2 = jnp.where(req2, packed[:, None], _INVALID).min(axis=0)
-        won2row = req2 & (packed[:, None] == grant2[None, :])
-        won2 = won2row.any(axis=1)
-        g2 = won2row.sum(axis=0).astype(I32)
+        # selects between booleans are spelled as logic: Mosaic cannot
+        # lower a bool-valued select
+        req2v = loser & (ej_ok | (~at_dst & has2))
+        won2, g2 = arbitrate(req2v & (oid == out2))
         won = won1 | won2
 
         # ---- final winner signals ----
-        b2c = jnp.minimum(best2, QN - 1)
-        qi_best = jnp.where(
-            won2, jnp.take_along_axis(qi_down, b2c[:, None], 1)[:, 0],
-            qi_best1,
-        )
-        bmin = jnp.where(
-            won2, jnp.take_along_axis(is_min, b2c[:, None], 1)[:, 0],
-            best_min1,
-        )
-        chosen = jnp.minimum(jnp.where(won2, best2, best), QN - 1)
-        esc = jnp.take_along_axis(escalate, chosen[:, None], 1)[:, 0]
-        a1t = jnp.take_along_axis(arr1, chosen[:, None], 1)[:, 0]
+        at_b2 = pcol == jnp.minimum(best2, QN - 1)
+        qi_best = jnp.where(won2, pick(at_b2, qi_down), qi_best1)
+        bmin = ((won2 & (at_b2 & is_min).any(axis=1, keepdims=True))
+                | (~won2 & best_min1))
+        at_chosen = pcol == jnp.minimum(jnp.where(won2, best2, best), QN - 1)
+        esc = (at_chosen & escalate).any(axis=1, keepdims=True)
 
         won_ref[0] = won.astype(I32)
         won2_ref[0] = won2.astype(I32)
         qib_ref[0] = qi_best
         bmin_ref[0] = bmin.astype(I32)
         esc_ref[0] = esc.astype(I32)
-        a1t_ref[0] = a1t
+        a1t_ref[0] = pick(at_chosen, arr1)
         g1_ref[0] = g1
         g2_ref[0] = g2
 
-    def blk(*tail):
-        return pl.BlockSpec((1,) + tail, lambda s, _t=len(tail): (s,) + (0,) * _t)
+    # one program per switch; the unit dims keep every block's two minor
+    # dims equal to the array's, as Mosaic's (8, 128) tiling requires
+    def per_switch(*tail):
+        return pl.BlockSpec((1,) + tail, lambda s: (s,) + (0,) * len(tail))
 
-    def full(*shape):
-        return pl.BlockSpec(shape, lambda s, _t=len(shape): (0,) * _t)
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda s: (0,) * len(shape))
 
+    head = per_switch(HS, 1)
+    row = per_switch(1, QN)
     call = pl.pallas_call(
         kernel,
         grid=(S,),
         in_specs=[
-            blk(HS), blk(HS), blk(HS), blk(HS), blk(HS), blk(HS),  # heads
-            blk(HS), blk(HS),                                      # rdsw, key
-            blk(HS, QN),                                           # jitter
-            blk(QN), blk(QN), blk(QN),                             # nbr/ipnb/lok
-            blk(OUT), blk(q),                                      # busy, ccur
-            full(S, q), full(QN), full(QN),                        # coords/pdim/pval
-            full(NQ), full(S * IN), full(1),                       # qlen/pocc/ndead
+            head, head, head, head, head, head, head,  # per-head columns
+            per_switch(HS, q), per_switch(HS, QN),     # dest coords, jitter
+            row, row, row, row,                        # cur_d/down/lok/occ
+            per_switch(1, OUT), per_switch(PV, QN),    # busy, qlen slab
+            whole(1, QN), whole(1, QN), whole(1, 1),   # pdim/pval/ndead
         ],
-        out_specs=[
-            blk(HS), blk(HS), blk(HS), blk(HS), blk(HS), blk(HS),
-            blk(OUT), blk(OUT),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((S, HS), I32) for _ in range(6)
-        ] + [
-            jax.ShapeDtypeStruct((S, OUT), I32) for _ in range(2)
-        ],
+        out_specs=[head] * 6 + [per_switch(1, OUT)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((S, HS, 1), I32)] * 6
+        + [jax.ShapeDtypeStruct((S, 1, OUT), I32)] * 2,
         interpret=interpret,
         name="route_arbitrate_fused",
     )
 
     def fused(exists, at_dst, dof, der, vcn, route_dsw, link_ok_t, n_dead_t,
               qlen, port_occ, busy_dec, jitter, packed):
-        def r2(x):
-            return x.astype(I32).reshape(S, HS)
+        def col(x):
+            return x.astype(I32).reshape(S, HS, 1)
 
+        # switch-level gathers hoisted out of the kernel: destination
+        # coordinates per head, congestion and queue lengths behind each
+        # of this switch's ports
+        cdst = st.coords[route_dsw].astype(I32).reshape(S, HS, q)
+        occ = port_occ[down_t].reshape(S, 1, QN)
+        slab = qlen.reshape(S * IN, PV)[down_t].transpose(0, 2, 1)
         outs = call(
-            r2(exists), r2(at_dst), r2(dof), r2(der), r2(vcn), hpool_t,
-            r2(route_dsw), packed.reshape(S, HS),
-            jitter.reshape(S, HS, QN),
-            nbr_t, ipnb_t, link_ok_t.astype(I32),
-            busy_dec.reshape(S, OUT), coords_t,
-            coords_t, pdim_t, pval_t,
-            qlen, port_occ, jnp.reshape(n_dead_t, (1,)).astype(I32),
+            col(exists), col(at_dst), col(dof), col(der), col(vcn), hpool_t,
+            order_keys(packed).reshape(S, HS, 1), cdst,
+            jitter.reshape(S, HS, QN), cur_d_t, down_t.reshape(S, 1, QN),
+            link_ok_t.astype(I32).reshape(S, 1, QN), occ,
+            busy_dec.reshape(S, 1, OUT), slab, pdim_t, pval_t,
+            jnp.reshape(n_dead_t, (1, 1)).astype(I32),
         )
         won, won2, qib, bmin, esc, a1t, g1, g2 = outs
         return (

@@ -13,9 +13,9 @@ sweep is **one compilation and one device call** (per shape bucket).
 ``run_seeds`` fans one scenario across many seeds without replicating its
 tables.  ``run_grid`` flattens a workload x seed cross product into a
 *lane* axis and shards it across every local device (``jax.shard_map``
-over a 1-D mesh, ``jax.pmap`` fallback, the nested-vmap path on a single
-device) — lanes are embarrassingly parallel, so an N-device host runs an
-N-times-wider grid at the same wall-clock per bucket.
+over a 1-D mesh; the nested-vmap path on a single device) — lanes are
+embarrassingly parallel, so an N-device host runs an N-times-wider grid
+at the same wall-clock per bucket.
 
 Engines are memoised by :func:`get_engine`; ``trace_count`` /
 ``device_calls`` expose how many XLA traces and dispatches actually
@@ -32,8 +32,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, PartitionSpec
 
-from repro.core.engine.cache import enable_persistent_cache
 from repro.core.engine.step import SimState, all_done, build_step, init_state
 from repro.core.engine.tables import build_static_tables
 from repro.core.engine.workload_tables import (
@@ -57,20 +57,11 @@ def default_lane_backend(ndev: int | None = None) -> str:
 
     Resolved at engine construction (and by the run manifest), not lazily
     at the first grid call: ``"vmap"`` on a single device, else
-    ``"shard_map"`` when the jax build exports it, else ``"pmap"``.
+    ``"shard_map"``.
     """
     if ndev is None:
         ndev = jax.local_device_count()
-    if ndev == 1:
-        return "vmap"
-    try:
-        try:
-            jax.shard_map  # type: ignore[attr-defined]
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map  # noqa: F401
-        return "shard_map"
-    except Exception:  # pragma: no cover - depends on jax build
-        return "pmap"
+    return "vmap" if ndev == 1 else "shard_map"
 
 
 def _index_outs(outs, idx):
@@ -148,9 +139,6 @@ class SimEngine:
         self.kernel = kernel
         self.chunk = chunk
         self.canon = canon
-        # opt-in persistent XLA compile cache (REPRO_COMPILE_CACHE env or an
-        # earlier enable_persistent_cache() call); no-op when unconfigured
-        enable_persistent_cache()
         self.static = build_static_tables(
             topo, mode=mode, num_pools=num_pools, max_deroutes=max_deroutes,
             cap=cap, penalty_packets=penalty_packets, arb=arb,
@@ -245,8 +233,7 @@ class SimEngine:
         ))
         self._lane_runner = None       # built lazily (multi-device only)
         # resolved at construction on every host shape (the run manifest
-        # records it); _make_lane_runner can still downgrade shard_map ->
-        # pmap if the mesh build fails at dispatch time
+        # records it)
         self.lane_backend = default_lane_backend()
 
     # ------------------------------------------------------------- prepare
@@ -409,7 +396,7 @@ class SimEngine:
 
     # ------------------------------------------------- device-sharded lanes
     def _make_lane_runner(self):
-        """Build the multi-device lane dispatcher (shard_map, else pmap).
+        """Build the multi-device lane dispatcher (``jax.shard_map``).
 
         Lanes — flattened (workload, seed) pairs with stacked tables — are
         embarrassingly parallel, so the dispatcher just splits the lane
@@ -417,46 +404,14 @@ class SimEngine:
         happens once per shape bucket (SPMD), which the trace-counter
         tests pin.
         """
-        ndev = jax.local_device_count()
-        try:
-            try:  # jax >= 0.6 exports shard_map at top level
-                shard_map = jax.shard_map  # type: ignore[attr-defined]
-            except AttributeError:
-                from jax.experimental.shard_map import shard_map
-            from jax.sharding import Mesh, PartitionSpec as P
-
-            mesh = Mesh(np.asarray(jax.devices()), ("lanes",))
-            fn = jax.jit(shard_map(
-                jax.vmap(self._core, in_axes=(0, 0, None)),
-                mesh=mesh,
-                in_specs=(P("lanes"), P("lanes"), None),
-                out_specs=P("lanes"),
-                check_rep=False,
-            ))
-            self.lane_backend = "shard_map"
-
-            def dispatch(stacked, seed_arr, horizon):
-                return fn(stacked, seed_arr, horizon)
-
-        except Exception:  # pragma: no cover - depends on jax build
-            pfn = jax.pmap(
-                jax.vmap(self._core, in_axes=(0, 0, None)),
-                in_axes=(0, 0, None),
-            )
-            self.lane_backend = "pmap"
-
-            def dispatch(stacked, seed_arr, horizon):
-                L = seed_arr.shape[0]
-                per = L // ndev
-                split = jax.tree_util.tree_map(
-                    lambda x: x.reshape((ndev, per) + x.shape[1:]), stacked
-                )
-                outs = pfn(split, seed_arr.reshape(ndev, per), horizon)
-                return jax.tree_util.tree_map(
-                    lambda o: o.reshape((L,) + o.shape[2:]), outs
-                )
-
-        return dispatch
+        mesh = Mesh(np.asarray(jax.devices()), ("lanes",))
+        return jax.jit(jax.shard_map(
+            jax.vmap(self._core, in_axes=(0, 0, None)),
+            mesh=mesh,
+            in_specs=(PartitionSpec("lanes"), PartitionSpec("lanes"), None),
+            out_specs=PartitionSpec("lanes"),
+            check_vma=False,
+        ))
 
     def run_grid(
         self,
@@ -470,10 +425,10 @@ class SimEngine:
         (workload, seed) pair, grouped by shape bucket) and dispatched
 
           * via ``jax.shard_map`` over a 1-D device mesh when the host has
-            more than one device (``jax.pmap`` when shard_map is
-            unavailable) — lanes are padded round-robin to a multiple of
-            the device count so uneven grids still compile once per
-            (bucket, lane-count) and every device receives equal work;
+            more than one device — lanes are padded round-robin to a
+            multiple of the device count so uneven grids still compile
+            once per (bucket, lane-count) and every device receives equal
+            work;
           * via the existing nested-vmap path (``run_batch_seeds``'s
             dispatch — seeds broadcast, tables never replicated) on a
             single device.

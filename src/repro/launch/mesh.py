@@ -17,7 +17,11 @@ def make_production_mesh(*, multi_pod: bool = False):
 
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # jax >= 0.7 makes mesh axes Explicit by default; the model code
+    # shards through with_sharding_constraint, which needs Auto axes
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_allocated_mesh(strategy: str = "diagonal", *, multi_pod: bool = False,

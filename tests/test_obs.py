@@ -152,7 +152,7 @@ def test_tracer_jsonl_manifest_and_report(tmp_path):
     assert manifest["suite"] == "unit"
     assert manifest["schema"] == obs_trace.SCHEMA
     assert manifest["config_hash"]
-    assert manifest["lane_backend"] in ("vmap", "pmap", "shard_map")
+    assert manifest["lane_backend"] in ("vmap", "shard_map")
 
     with open(os.path.join(d, "events.jsonl")) as f:
         events = [json.loads(line) for line in f]

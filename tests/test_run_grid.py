@@ -118,7 +118,7 @@ def test_run_grid_sharded_matches_single_device():
                        capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stderr
     payload = json.loads(r.stdout.strip().splitlines()[-1])
-    assert payload["backend"] in ("shard_map", "pmap")
+    assert payload["backend"] == "shard_map"
     # lane_backend is reported from construction and the first run_grid
     # must dispatch through that same backend
     assert payload["pre_backend"] == payload["backend"]
