@@ -7,7 +7,6 @@
                                              [--grids a,b,...]
                                              [--kernel lax|pallas]
                                              [--chunk K] [--canon]
-                                             [--profile DIR]
 
 Runs the canonical grids (strategy / pattern / fault sweeps on the paper
 machine) through a **fresh** ``SimEngine`` each — so compile time is
@@ -45,10 +44,8 @@ arbitration / fused route+arbitrate megakernel), ``--chunk K``
 canonicalization; its compile-key hit rate lands in the snapshot).  The
 persistent XLA compile cache is always on: ``JAX_COMPILATION_CACHE_DIR``
 when set, else ``<repo>/.jax_cache`` (see ``repro.core.engine.cache``).
-``--profile DIR`` runs one extra, horizon-clamped dispatch per grid
-under a ``jax.profiler`` trace inside an obs trace dir (timing itself is
-never profiled), so ``repro.obs.report`` renders per-grid device
-timelines next to the usual span tables.
+Device time by cycle stage and host time by engine stage are the chip
+benchmark's (``python3 bench/run.py ... --trace 1``), not this file's.
 """
 
 from __future__ import annotations
@@ -71,19 +68,12 @@ from benchmarks.common import (
 )
 
 from repro.core.engine import PACKET_FLITS, SimEngine, enable_persistent_cache
-from repro.obs import trace as obs_trace
 from repro.obs.trace import manifest_dict
 from repro.route import apply_faults, random_link_faults
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HISTORY_PATH = os.path.join(REPO_ROOT, "BENCH_history.jsonl")
 SCHEMA = 1
-# Horizon clamp for the extra profiled dispatch (see measure_grid).  The
-# tracer records every HLO-op execution, and one engine cycle is a very
-# large graph (~20 s and ~50 MB of xplane PER CYCLE on CPU), so the
-# profiled dispatch samples just two cycles — enough for the op-level
-# breakdown; headline timings never come from the profiled run.
-PROFILE_HORIZON = 2
 DEFAULT_THRESHOLD = 0.10
 EXIT_REGRESSION = 2
 EXIT_BAD_BASELINE = 3
@@ -130,8 +120,7 @@ GRIDS = {
 # ----------------------------------------------------------------- measuring
 def measure_grid(workloads, seeds, mode, horizon,
                  topo=PAPER_TOPO, arb: str = "lax", kernel: str = "lax",
-                 chunk: int = 1, canon: bool = False,
-                 profile_dir: str | None = None) -> dict:
+                 chunk: int = 1, canon: bool = False) -> dict:
     """Time one grid through a fresh engine: compile vs steady-state.
 
     The engine is constructed directly (bypassing the ``get_engine``
@@ -141,12 +130,7 @@ def measure_grid(workloads, seeds, mode, horizon,
     on the host, so the wall clock brackets full device execution.
     ``wall_first_s`` / ``wall_repeat_s`` record the two raw calls — the
     pair the persistent compile cache moves (a cache-warm process pays
-    steady-state on its *first* call).  ``profile_dir`` runs one EXTRA
-    dispatch after timing under a ``jax.profiler`` trace, with the
-    horizon clamped to ``PROFILE_HORIZON`` cycles: the tracer emits an
-    event per HLO-op execution, so profiling a full-horizon dispatch
-    balloons to hours and GBs.  The clamped dispatch has the same
-    per-cycle op profile; timing is never taken under the profiler.
+    steady-state on its *first* call).
     """
     num_pools = {w.num_pools for w in workloads}
     if len(num_pools) != 1:
@@ -161,12 +145,6 @@ def measure_grid(workloads, seeds, mode, horizon,
     t1 = time.perf_counter()
     engine.run_grid(preps, seeds=seeds, horizon=horizon)
     t2 = time.perf_counter()
-
-    if profile_dir:
-        os.makedirs(profile_dir, exist_ok=True)
-        with jax.profiler.trace(profile_dir):
-            engine.run_grid(preps, seeds=seeds,
-                            horizon=min(horizon, PROFILE_HORIZON))
 
     device_s = t2 - t1
     compile_s = max((t1 - t0) - device_s, 0.0)
@@ -208,16 +186,8 @@ def current_rev() -> str:
 
 
 def run_suite(quick: bool = True, grids=None, arb: str = "lax",
-              kernel: str = "lax", chunk: int = 1, canon: bool = False,
-              profile: str | None = None) -> dict:
-    """Measure every requested grid; returns the BENCH json payload.
-
-    ``profile`` is an obs trace directory: each grid is measured inside a
-    ``perf.grid`` span with its ``jax.profiler`` trace under
-    ``<profile>/xprof/<grid>/``, and a ``perf.grid_metrics`` event carries
-    the headline numbers so :mod:`repro.obs.report` can render the
-    device-timeline table without re-running anything.
-    """
+              kernel: str = "lax", chunk: int = 1, canon: bool = False) -> dict:
+    """Measure every requested grid; returns the BENCH json payload."""
     names = list(GRIDS) if not grids else [g for g in GRIDS if g in grids]
     knobs = {"arb": arb, "kernel": kernel, "chunk": chunk, "canon": canon}
     bench = {
@@ -235,21 +205,11 @@ def run_suite(quick: bool = True, grids=None, arb: str = "lax",
     }
     for name in names:
         wls, seeds, mode, horizon = GRIDS[name](quick)
-        pdir = os.path.join(profile, "xprof", name) if profile else None
         print(f"# measuring {name} ({len(wls)} workloads x "
               f"{len(seeds)} seeds)...", file=sys.stderr)
-        with obs_trace.span("perf.grid", grid=name, **knobs):
-            m = measure_grid(wls, seeds, mode, horizon, arb=arb,
-                             kernel=kernel, chunk=chunk, canon=canon,
-                             profile_dir=pdir)
-        if profile:
-            obs_trace.event(
-                "perf.grid_metrics", grid=name, xprof=pdir or "",
-                **{k: m[k] for k in ("lanes", "compile_s", "device_s",
-                                     "wall_first_s", "wall_repeat_s",
-                                     "cycles_per_s", "bucket_hit_rate")},
-            )
-        bench["grids"][name] = m
+        bench["grids"][name] = measure_grid(
+            wls, seeds, mode, horizon, arb=arb, kernel=kernel, chunk=chunk,
+            canon=canon)
     return bench
 
 
@@ -367,10 +327,6 @@ def main(argv=None) -> int:
     p.add_argument("--canon", action="store_true",
                    help="pow2-canonicalize batch-axis lengths (compile "
                         "sharing across nearby grid sizes)")
-    p.add_argument("--profile", default=None, metavar="DIR",
-                   help="obs trace dir: wrap each grid in a jax.profiler "
-                        "trace (<DIR>/xprof/<grid>/) + span/metric events "
-                        "for repro.obs.report")
     p.add_argument("--history", default=None, metavar="PATH",
                    help="history jsonl to append/compare "
                         "(default <repo>/BENCH_history.jsonl)")
@@ -418,17 +374,8 @@ def main(argv=None) -> int:
                   "snapshot (missing 'grids' table)", file=sys.stderr)
             return EXIT_BAD_BASELINE
 
-    tracer = None
-    if args.profile:
-        tracer = obs_trace.configure(args.profile, kind="perf_profile",
-                                     rev=current_rev())
-    try:
-        bench = run_suite(quick=not args.full, grids=grids, arb=args.arb,
-                          kernel=args.kernel, chunk=args.chunk,
-                          canon=args.canon, profile=args.profile)
-    finally:
-        if tracer is not None:
-            obs_trace.disable()
+    bench = run_suite(quick=not args.full, grids=grids, arb=args.arb,
+                      kernel=args.kernel, chunk=args.chunk, canon=args.canon)
     out = args.out or os.path.join(REPO_ROOT, f"BENCH_{bench['rev']}.json")
     os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
     with open(out, "w") as f:

@@ -125,7 +125,7 @@ def make_arbiter(
         out_shape=[jax.ShapeDtypeStruct((S, HS, 1), I32),
                    jax.ShapeDtypeStruct((S, 1, OUT), I32)],
         interpret=interpret,
-        name="switch_arbitration",
+        name="arbitrate",
     )
 
     def arbiter(req_out, packed):

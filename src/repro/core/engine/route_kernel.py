@@ -250,7 +250,7 @@ def make_fused_router(st, interpret: bool | None = None) -> Callable:
         out_shape=[jax.ShapeDtypeStruct((S, HS, 1), I32)] * 6
         + [jax.ShapeDtypeStruct((S, 1, OUT), I32)] * 2,
         interpret=interpret,
-        name="route_arbitrate_fused",
+        name="route_arbitrate",
     )
 
     def fused(exists, at_dst, dof, der, vcn, route_dsw, link_ok_t, n_dead_t,

@@ -24,7 +24,6 @@ happened (the benchmark suite and the trace-counter tests assert on them).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from typing import Sequence
@@ -278,8 +277,9 @@ class SimEngine:
         tgt = self._canon_pad(len(idxs))
         return idxs + [idxs[k % len(idxs)] for k in range(tgt - len(idxs))]
 
-    def _note_bucket(self, fn: str, bucket, dims: tuple) -> None:
-        """Account one dispatch against the compile-key it lands on.
+    def _note_bucket(self, fn: str, bucket, dims: tuple) -> bool:
+        """Account one dispatch against the compile-key it lands on;
+        returns whether the key is new.
 
         ``(fn, shape bucket, batch dims)`` mirrors the jit cache key of
         the dispatched callable — a *miss* is a dispatch that opens a new
@@ -290,9 +290,10 @@ class SimEngine:
         key = (fn, bucket, dims)
         if key in self._seen_keys:
             self.bucket_hits += 1
-        else:
-            self.bucket_misses += 1
-            self._seen_keys.add(key)
+            return False
+        self.bucket_misses += 1
+        self._seen_keys.add(key)
+        return True
 
     def bucket_stats(self) -> dict:
         """Compile-key hit/miss counters for this engine's dispatches."""
@@ -310,12 +311,14 @@ class SimEngine:
         seed: int = 0,
         horizon: int = 60_000,
     ) -> SimResult:
-        prep = self.prepare(wl)
-        self.device_calls += 1
-        self._note_bucket("run1", prep.tables.shape_bucket, ())
-        with self._dispatch_span("run", lanes=1):
-            out = self._run1(prep.tables, jnp.int32(seed), jnp.int32(horizon))
-        return self._to_result(out, prep)
+        with obs_trace.stage("engine.prepare", api="run", workloads=1):
+            prep = self.prepare(wl)
+        out = self._dispatch(
+            "run", self._run1, "run1", prep.tables.shape_bucket, (), 1,
+            prep.tables, jnp.int32(seed), horizon,
+        )
+        with self._results_span(out, 1):
+            return self._to_result(out, prep)
 
     def run_batch(
         self,
@@ -332,7 +335,7 @@ class SimEngine:
         (e.g. one batch per kernel over a fixed strategy set) share one
         compilation.
         """
-        preps = [self.prepare(w) for w in workloads]
+        preps = self._prepare_all("run_batch", workloads)
         if seeds is None:
             seeds = [0] * len(preps)
         if len(seeds) != len(preps):
@@ -347,17 +350,18 @@ class SimEngine:
             # canon: pad the stacked axis to a power of two (padded lanes
             # repeat real ones; their rows are simply never read back)
             idxs_p = self._pad_idxs(idxs)
-            stacked = stack_tables([preps[i].tables for i in idxs_p])
-            seed_arr = jnp.asarray(
-                [int(seeds[i]) for i in idxs_p], dtype=jnp.int32
+            stacked, seed_arr = self._stack(
+                preps, idxs_p, [int(seeds[i]) for i in idxs_p]
             )
-            self.device_calls += 1
-            self._note_bucket("runN", preps[idxs[0]].tables.shape_bucket,
-                              (len(idxs_p),))
-            with self._dispatch_span("run_batch", lanes=len(idxs_p)):
-                outs = self._runN(stacked, seed_arr, jnp.int32(horizon))
-            for j, i in enumerate(idxs):
-                results[i] = self._to_result(_index_outs(outs, j), preps[i])
+            outs = self._dispatch(
+                "run_batch", self._runN, "runN",
+                preps[idxs[0]].tables.shape_bucket, (len(idxs_p),),
+                len(idxs), stacked, seed_arr, horizon,
+            )
+            with self._results_span(outs, len(idxs)):
+                for j, i in enumerate(idxs):
+                    results[i] = self._to_result(_index_outs(outs, j),
+                                                 preps[i])
         return results  # type: ignore[return-value]
 
     def run_batch_seeds(
@@ -371,28 +375,10 @@ class SimEngine:
         broadcast), so nothing is replicated per seed.  Returns
         ``results[workload][seed]`` in input order.
         """
-        preps = [self.prepare(w) for w in workloads]
-        seeds_p = self._pad_idxs([int(s) for s in seeds])
-        seed_arr = jnp.asarray(seeds_p, dtype=jnp.int32)
-        groups: dict[tuple[int, int, int, int], list[int]] = {}
-        for i, p in enumerate(preps):
-            groups.setdefault(p.tables.shape_bucket, []).append(i)
-        results: list[list[SimResult] | None] = [None] * len(preps)
-        for idxs in groups.values():
-            idxs_p = self._pad_idxs(idxs)
-            stacked = stack_tables([preps[i].tables for i in idxs_p])
-            self.device_calls += 1
-            self._note_bucket("runNS", preps[idxs[0]].tables.shape_bucket,
-                              (len(idxs_p), len(seeds_p)))
-            with self._dispatch_span("run_batch_seeds",
-                                     lanes=len(idxs_p) * len(seeds_p)):
-                outs = self._runNS(stacked, seed_arr, jnp.int32(horizon))
-            for j, i in enumerate(idxs):
-                results[i] = [
-                    self._to_result(_index_outs(outs, (j, k)), preps[i])
-                    for k in range(len(seeds))
-                ]
-        return results  # type: ignore[return-value]
+        preps = self._prepare_all("run_batch_seeds", workloads)
+        return self._run_cross(
+            "run_batch_seeds", preps, [int(s) for s in seeds], horizon
+        )
 
     # ------------------------------------------------- device-sharded lanes
     def _make_lane_runner(self):
@@ -438,36 +424,18 @@ class SimEngine:
         come back as ``results[workload][seed]`` in input order.
         ``self.lane_backend`` records which dispatcher ran.
         """
-        preps = [self.prepare(w) for w in workloads]
-        seeds = [0] if seeds is None else list(seeds)
+        preps = self._prepare_all("run_grid", workloads)
+        seeds = [0] if seeds is None else [int(s) for s in seeds]
         ndev = jax.local_device_count()
+        if ndev == 1:
+            # single device: the nested-vmap cross product is already the
+            # fastest layout (no table replication across the seed axis)
+            return self._run_cross("run_grid", preps, seeds, horizon)
+
         groups: dict[tuple[int, int, int, int], list[int]] = {}
         for i, p in enumerate(preps):
             groups.setdefault(p.tables.shape_bucket, []).append(i)
         results: list[list[SimResult] | None] = [None] * len(preps)
-        if ndev == 1:
-            # single device: the nested-vmap cross product is already the
-            # fastest layout (no table replication across the seed axis)
-            seeds_p = self._pad_idxs([int(s) for s in seeds])
-            seed_arr = jnp.asarray(seeds_p, dtype=jnp.int32)
-            for idxs in groups.values():
-                idxs_p = self._pad_idxs(idxs)
-                stacked = stack_tables([preps[i].tables for i in idxs_p])
-                self.device_calls += 1
-                self._note_bucket(
-                    "runNS", preps[idxs[0]].tables.shape_bucket,
-                    (len(idxs_p), len(seeds_p)),
-                )
-                with self._dispatch_span("run_grid",
-                                         lanes=len(idxs_p) * len(seeds_p)):
-                    outs = self._runNS(stacked, seed_arr, jnp.int32(horizon))
-                for j, i in enumerate(idxs):
-                    results[i] = [
-                        self._to_result(_index_outs(outs, (j, k)), preps[i])
-                        for k in range(len(seeds))
-                    ]
-            return results  # type: ignore[return-value]
-
         if self._lane_runner is None:
             self._lane_runner = self._make_lane_runner()
         for idxs in groups.values():
@@ -480,20 +448,21 @@ class SimEngine:
             # round-robin padding: repeat existing lanes so every device
             # shard is full; padded lanes are computed and discarded
             lanes_p = lanes + [lanes[k % len(lanes)] for k in range(pad)]
-            stacked = stack_tables([preps[i].tables for i, _ in lanes_p])
-            seed_arr = jnp.asarray([int(seeds[k]) for _, k in lanes_p],
-                                   dtype=jnp.int32)
-            self.device_calls += 1
-            self._note_bucket("lanes", preps[idxs[0]].tables.shape_bucket,
-                              (len(lanes_p),))
-            with self._dispatch_span("run_grid", lanes=len(lanes_p)):
-                outs = self._lane_runner(stacked, seed_arr, jnp.int32(horizon))
-            for lane, (i, k) in enumerate(lanes):
-                if results[i] is None:
-                    results[i] = [None] * len(seeds)  # type: ignore[list-item]
-                results[i][k] = self._to_result(
-                    _index_outs(outs, lane), preps[i]
-                )
+            stacked, seed_arr = self._stack(
+                preps, [i for i, _ in lanes_p], [seeds[k] for _, k in lanes_p]
+            )
+            outs = self._dispatch(
+                "run_grid", self._lane_runner, "lanes",
+                preps[idxs[0]].tables.shape_bucket, (len(lanes_p),),
+                len(lanes), stacked, seed_arr, horizon,
+            )
+            with self._results_span(outs, len(lanes)):
+                for lane, (i, k) in enumerate(lanes):
+                    if results[i] is None:
+                        results[i] = [None] * len(seeds)  # type: ignore
+                    results[i][k] = self._to_result(
+                        _index_outs(outs, lane), preps[i]
+                    )
         return results  # type: ignore[return-value]
 
     def run_seeds(
@@ -503,17 +472,19 @@ class SimEngine:
         horizon: int = 60_000,
     ) -> list[SimResult]:
         """One scenario, many seeds — tables are not replicated on device."""
-        prep = self.prepare(wl)
+        with obs_trace.stage("engine.prepare", api="run_seeds", workloads=1):
+            prep = self.prepare(wl)
         seeds_p = self._pad_idxs([int(s) for s in seeds])
         seed_arr = jnp.asarray(seeds_p, dtype=jnp.int32)
-        self.device_calls += 1
-        self._note_bucket("runS", prep.tables.shape_bucket, (len(seeds_p),))
-        with self._dispatch_span("run_seeds", lanes=len(seeds_p)):
-            outs = self._runS(prep.tables, seed_arr, jnp.int32(horizon))
-        return [
-            self._to_result(_index_outs(outs, j), prep)
-            for j in range(len(seeds))
-        ]
+        outs = self._dispatch(
+            "run_seeds", self._runS, "runS", prep.tables.shape_bucket,
+            (len(seeds_p),), len(seeds), prep.tables, seed_arr, horizon,
+        )
+        with self._results_span(outs, len(seeds)):
+            return [
+                self._to_result(_index_outs(outs, j), prep)
+                for j in range(len(seeds))
+            ]
 
     def run_debug(
         self,
@@ -540,22 +511,78 @@ class SimEngine:
         )
 
     # ------------------------------------------------------------ private
-    @contextlib.contextmanager
-    def _dispatch_span(self, api: str, lanes: int):
-        """Span one device dispatch (and flag fresh compiles) when a
-        tracer is active; a bare yield — no timing, no allocation — when
-        tracing is off."""
-        tracer = obs_trace.active()
-        if tracer is None:
-            yield
-            return
+    # Host stages of a call, each an ``obs.trace.stage`` span (on the
+    # profiler's clock, and in the JSONL log while a tracer is active):
+    # engine.prepare -> engine.stack -> engine.dispatch -> engine.to_result.
+    def _prepare_all(self, api: str, workloads) -> list[PreparedWorkload]:
+        with obs_trace.stage("engine.prepare", api=api,
+                             workloads=len(workloads)):
+            return [self.prepare(w) for w in workloads]
+
+    def _stack(self, preps, idxs, seeds) -> tuple[WorkloadTables, jax.Array]:
+        """The stacked tables of ``preps[idxs]`` and the seed array."""
+        with obs_trace.stage("engine.stack", tables=len(idxs)):
+            return (stack_tables([preps[i].tables for i in idxs]),
+                    jnp.asarray(seeds, dtype=jnp.int32))
+
+    def _run_cross(self, api, preps, seeds, horizon):
+        """The workload x seed cross product through the nested vmap, one
+        dispatch per shape bucket (``run_batch_seeds``, and ``run_grid``
+        on one device)."""
+        seeds_p = self._pad_idxs(seeds)
+        groups: dict[tuple[int, int, int, int], list[int]] = {}
+        for i, p in enumerate(preps):
+            groups.setdefault(p.tables.shape_bucket, []).append(i)
+        results: list[list[SimResult] | None] = [None] * len(preps)
+        for idxs in groups.values():
+            idxs_p = self._pad_idxs(idxs)
+            stacked, seed_arr = self._stack(preps, idxs_p, seeds_p)
+            outs = self._dispatch(
+                api, self._runNS, "runNS",
+                preps[idxs[0]].tables.shape_bucket,
+                (len(idxs_p), len(seeds_p)), len(idxs) * len(seeds),
+                stacked, seed_arr, horizon,
+            )
+            with self._results_span(outs, len(idxs) * len(seeds)):
+                for j, i in enumerate(idxs):
+                    results[i] = [
+                        self._to_result(_index_outs(outs, (j, k)), preps[i])
+                        for k in range(len(seeds))
+                    ]
+        return results
+
+    def _dispatch(self, api, runner, fn, bucket, dims, lanes, tables, seeds,
+                  horizon):
+        """One device dispatch, waited for: the ``engine.dispatch`` span
+        covers the enqueue and the device run up to its last output
+        (``_to_result`` would wait there anyway), so it times the device
+        call.  ``lanes`` counts the lanes asked for, ``padded_lanes`` the
+        lanes run (``dims``, the batch axes); ``new_key`` marks the first
+        dispatch of a compile key, so a compile shows on the profiler's
+        clock.  An ``engine.compile`` event follows a dispatch that traced
+        new code while a tracer is active."""
+        self.device_calls += 1
+        new_key = self._note_bucket(fn, bucket, dims)
         traces0 = self.trace_count
-        with tracer.span("engine.dispatch", api=api, mode=self.mode,
-                         lanes=lanes, backend=self.lane_backend):
-            yield
+        with obs_trace.stage(
+            "engine.dispatch", api=api, mode=self.mode, lanes=lanes,
+            padded_lanes=int(np.prod(dims)), bucket=str(bucket),
+            new_key=new_key, backend=self.lane_backend,
+        ):
+            outs = jax.block_until_ready(
+                runner(tables, seeds, jnp.int32(horizon))
+            )
         if self.trace_count > traces0:
-            tracer.event("engine.compile", api=api, mode=self.mode,
-                         traces=self.trace_count - traces0)
+            obs_trace.event("engine.compile", api=api, mode=self.mode,
+                            traces=self.trace_count - traces0)
+        return outs
+
+    def _results_span(self, outs, lanes: int):
+        """The ``engine.to_result`` span: ``lanes`` results, each of
+        ``arrays`` device arrays brought to the host."""
+        arrays = lanes * len(jax.tree_util.tree_leaves(outs))
+        return obs_trace.stage("engine.to_result", lanes=lanes,
+                               arrays=arrays)
 
     def _to_result(self, out, prep: PreparedWorkload) -> SimResult:
         tel = None

@@ -123,6 +123,14 @@ def build_step(
     grants, and delivery latencies.  The probe updates are pure extra
     scatters appended after the physics; the simulated trajectory is
     bit-identical either way (pinned in ``tests/test_obs.py``).
+
+    Every operation of a cycle runs under one ``jax.named_scope`` naming
+    its stage, in order: ``heads``, ``route`` and ``arbitrate`` (or
+    ``route_arbitrate``, the fused kernel), ``dequeue``, ``deliver``,
+    ``move``, ``complete``, ``inject`` and ``telemetry``.  Scopes are
+    metadata only, so the optimised program is the same without them;
+    the profiler tags each device op with its scope path (``tf_op``),
+    from which the benchmark reads device time per stage.
     """
     S, E, IN, OUT = st.S, st.E, st.IN, st.OUT
     P, V, NQ, H, CAP = st.P, st.V, st.NQ, st.H, st.CAP
@@ -155,449 +163,458 @@ def build_step(
             state: SimState = carry
         else:
             state, tel = carry
-        R, T = wt.R, wt.T
-        MAXD = wt.D
-        t = state.t
-        # fault epochs: select the mask (and its derived pool/reserve data)
-        # active at cycle t.  NE is a *shape*, so this branch resolves at
-        # trace time: the NE == 1 constant slice is the static-fault path,
-        # bit-identical to the pre-epoch kernel (trace-counter-pinned);
-        # NE > 1 pays exactly one gather on the epoch index per cycle.
-        NE = wt.NE
-        if NE == 1:
-            ei = jnp.int32(0)
-            link_ok_t = wt.link_ok[0]
-            mid_pool_t = wt.mid_pool[0]
-            n_mid_t = wt.n_mid[0]
-            n_dead_t = wt.n_dead[0]
-        else:
-            ei = (jnp.sum(t >= wt.epoch_start.astype(I32)) - 1).astype(I32)
-            link_ok_t = wt.link_ok[ei]
-            mid_pool_t = wt.mid_pool[ei]
-            n_mid_t = wt.n_mid[ei]
-            n_dead_t = wt.n_dead[ei]
-        key = jax.random.fold_in(state.key, t)
-        # policies without intermediates split 3 keys exactly like the seed
-        # engine, preserving bit-identical min/omniwar trajectories
-        if use_imd:
-            k_arb, k_jit, k_smp, k_mid = jax.random.split(key, 4)
-        else:
-            k_arb, k_jit, k_smp = jax.random.split(key, 3)
+        with jax.named_scope("heads"):
+            R, T = wt.R, wt.T
+            MAXD = wt.D
+            t = state.t
+            # fault epochs: select the mask (and its derived pool/reserve data)
+            # active at cycle t.  NE is a *shape*, so this branch resolves at
+            # trace time: the NE == 1 constant slice is the static-fault path,
+            # bit-identical to the pre-epoch kernel (trace-counter-pinned);
+            # NE > 1 pays exactly one gather on the epoch index per cycle.
+            NE = wt.NE
+            if NE == 1:
+                ei = jnp.int32(0)
+                link_ok_t = wt.link_ok[0]
+                mid_pool_t = wt.mid_pool[0]
+                n_mid_t = wt.n_mid[0]
+                n_dead_t = wt.n_dead[0]
+            else:
+                ei = (jnp.sum(t >= wt.epoch_start.astype(I32)) - 1).astype(I32)
+                link_ok_t = wt.link_ok[ei]
+                mid_pool_t = wt.mid_pool[ei]
+                n_mid_t = wt.n_mid[ei]
+                n_dead_t = wt.n_dead[ei]
+            key = jax.random.fold_in(state.key, t)
+            # policies without intermediates split 3 keys exactly like the seed
+            # engine, preserving bit-identical min/omniwar trajectories
+            if use_imd:
+                k_arb, k_jit, k_smp, k_mid = jax.random.split(key, 4)
+            else:
+                k_arb, k_jit, k_smp = jax.random.split(key, 3)
 
-        qlen, qhead = state.qlen, state.qhead
-        # per-(switch, in-port) total occupancy (packets over all pools+VCs):
-        # the adaptive-routing congestion signal (CAMINOS counts phits in the
-        # whole input buffer; penalty/range ratio ~1/8 is preserved).
-        port_occ = qlen.reshape(S * IN, P * V).sum(axis=1)
+            qlen, qhead = state.qlen, state.qhead
+            # per-(switch, in-port) total occupancy (packets over all pools+VCs):
+            # the adaptive-routing congestion signal (CAMINOS counts phits in the
+            # whole input buffer; penalty/range ratio ~1/8 is preserved).
+            port_occ = qlen.reshape(S * IN, P * V).sum(axis=1)
 
-        # ---------------- heads --------------------------------------------
-        exists = qlen > 0                                   # (H,)
-        slot = jnp.arange(H, dtype=I32) * CAP + qhead
-        dst = state.f_dst[slot]
-        der = state.f_der[slot]
-        hop = state.f_hop[slot]
-        dsw = dst // conc
-        dof = dst % conc
+            # the packet at the front of every queue
+            exists = qlen > 0                                   # (H,)
+            slot = jnp.arange(H, dtype=I32) * CAP + qhead
+            dst = state.f_dst[slot]
+            der = state.f_der[slot]
+            hop = state.f_hop[slot]
+            dsw = dst // conc
+            dof = dst % conc
 
-        cur = h_sw
-        at_dst = cur == dsw
+            cur = h_sw
+            at_dst = cur == dsw
 
-        # Valiant phase 1 routes toward the packet's intermediate switch;
-        # reaching it (or the final destination early) flips to phase 2.
-        if use_imd:
-            imd = state.f_imd[slot]
-            in_phase1 = (imd < S) & (imd != cur) & ~at_dst
-            route_dsw = jnp.where(in_phase1, imd, dsw)
-        else:
-            route_dsw = dsw
+            # Valiant phase 1 routes toward the packet's intermediate switch;
+            # reaching it (or the final destination early) flips to phase 2.
+            if use_imd:
+                imd = state.f_imd[slot]
+                in_phase1 = (imd < S) & (imd != cur) & ~at_dst
+                route_dsw = jnp.where(in_phase1, imd, dsw)
+            else:
+                route_dsw = dsw
 
-        # shared pre-kernel signals: the RNG draws must come off the host
-        # key stream identically on both kernel paths (bit-exactness)
-        busy_dec = jnp.maximum(state.busy - 1, 0)           # link served 1 pkt
-        vcn = jnp.minimum(hop + 1, V - 1)                   # (H,) next VC
-        jitter = jax.random.randint(k_jit, (H, q * n), 0, 8, dtype=I32)
-        arb_key = jax.random.bits(k_arb, (H,), dtype=U32) >> 17  # 15 bits
-        packed = (arb_key << 17) | jnp.arange(H, dtype=U32)
+            # shared pre-kernel signals: the RNG draws must come off the host
+            # key stream identically on both kernel paths (bit-exactness)
+            busy_dec = jnp.maximum(state.busy - 1, 0)           # link served 1 pkt
+            vcn = jnp.minimum(hop + 1, V - 1)                   # (H,) next VC
+            jitter = jax.random.randint(k_jit, (H, q * n), 0, 8, dtype=I32)
+            arb_key = jax.random.bits(k_arb, (H,), dtype=U32) >> 17  # 15 bits
+            packed = (arb_key << 17) | jnp.arange(H, dtype=U32)
 
         def route_arbitrate_lax():
-            # ---------- routing: candidate network ports (lax path) --------
-            ccur = coords[cur]                              # (H, q)
-            cdst = coords[route_dsw]                        # (H, q)
-            pv = port_val[None, :]                          # (1, q*n)
-            cur_d = ccur[:, port_dim]                       # (H, q*n)
-            dst_d = cdst[:, port_dim]
-            unaligned = cur_d != dst_d                      # (H, q*n)
-            not_self = pv != cur_d
-            is_min = (pv == dst_d) & unaligned
-            healthy = link_ok_t[cur]                        # (H, q*n) faults
-            nb = nbr[cur].astype(I32)                       # (H, q*n)
-            ipnb = in_port_at_nb[cur].astype(I32)           # (H, q*n)
-            qi_down = ((nb * IN + ipnb) * P + h_pool[:, None]) * V + vcn[:, None]
-            room = qlen[qi_down] < CAP                      # own queue has space
-            occ = port_occ[nb * IN + ipnb]                  # congestion signal
-            avail_net = busy_dec[
-                cur[:, None] * OUT + jnp.arange(q * n)[None, :]
-            ] < 2
-            if policy.adaptive_deroutes:
-                # Omni-WAR: deroutes in any unaligned dimension while budget
-                # lasts; dead links drop out of the candidate set.  Under
-                # faults, voluntary deroutes must keep a *reserve* (one unit
-                # per dead cable) so the budget can't be spent before a
-                # forced escape is needed — a packet stranded at a dead
-                # minimal link with der == 0 would wait forever.  The cap at
-                # m - 1 keeps one voluntary deroute alive at any fault count
-                # (a full-budget reserve would silently collapse omniwar
-                # into min-with-escalation machine-wide); the escalation
-                # term covers forced escapes below the reserve, exactly
-                # like the minimal-only policies.
-                reserve = jnp.minimum(n_dead_t, max(m - 1, 0))
-                base = unaligned & not_self & healthy
-                escalate = (
-                    ~(is_min & healthy).any(axis=1, keepdims=True)
-                    & base & (der[:, None] > 0)
+            with jax.named_scope("route"):
+                # routing: candidate network ports (lax path)
+                ccur = coords[cur]                              # (H, q)
+                cdst = coords[route_dsw]                        # (H, q)
+                pv = port_val[None, :]                          # (1, q*n)
+                cur_d = ccur[:, port_dim]                       # (H, q*n)
+                dst_d = cdst[:, port_dim]
+                unaligned = cur_d != dst_d                      # (H, q*n)
+                not_self = pv != cur_d
+                is_min = (pv == dst_d) & unaligned
+                healthy = link_ok_t[cur]                        # (H, q*n) faults
+                nb = nbr[cur].astype(I32)                       # (H, q*n)
+                ipnb = in_port_at_nb[cur].astype(I32)           # (H, q*n)
+                qi_down = ((nb * IN + ipnb) * P + h_pool[:, None]) * V + vcn[:, None]
+                room = qlen[qi_down] < CAP                      # own queue has space
+                occ = port_occ[nb * IN + ipnb]                  # congestion signal
+                avail_net = busy_dec[
+                    cur[:, None] * OUT + jnp.arange(q * n)[None, :]
+                ] < 2
+                if policy.adaptive_deroutes:
+                    # Omni-WAR: deroutes in any unaligned dimension while budget
+                    # lasts; dead links drop out of the candidate set.  Under
+                    # faults, voluntary deroutes must keep a *reserve* (one unit
+                    # per dead cable) so the budget can't be spent before a
+                    # forced escape is needed — a packet stranded at a dead
+                    # minimal link with der == 0 would wait forever.  The cap at
+                    # m - 1 keeps one voluntary deroute alive at any fault count
+                    # (a full-budget reserve would silently collapse omniwar
+                    # into min-with-escalation machine-wide); the escalation
+                    # term covers forced escapes below the reserve, exactly
+                    # like the minimal-only policies.
+                    reserve = jnp.minimum(n_dead_t, max(m - 1, 0))
+                    base = unaligned & not_self & healthy
+                    escalate = (
+                        ~(is_min & healthy).any(axis=1, keepdims=True)
+                        & base & (der[:, None] > 0)
+                    )
+                    legal = (
+                        (base & (is_min | (der[:, None] > reserve)) | escalate)
+                        & room & avail_net
+                    )
+                else:
+                    # minimal-only (min / val / ugal): when every minimal port of
+                    # this switch is dead, escalate to budget-bounded deroutes so
+                    # packets can round the fault (hops stay inside the VC budget)
+                    is_min_h = is_min & healthy
+                    escalate = (
+                        ~is_min_h.any(axis=1, keepdims=True)
+                        & unaligned & not_self & healthy & (der[:, None] > 0)
+                    )
+                    legal = (is_min_h | escalate) & room & avail_net
+                cost = occ * 8 + PEN * (~is_min) + jitter
+                cost = jnp.where(legal, cost, BIGCOST)
+                best = jnp.argmin(cost, axis=1).astype(I32)     # (H,)
+                best_cost = jnp.take_along_axis(cost, best[:, None], 1)[:, 0]
+                has_port = best_cost < BIGCOST
+                best_min = jnp.take_along_axis(is_min, best[:, None], 1)[:, 0]
+
+                out_port = jnp.where(at_dst, q * n + dof, best)
+                requesting = exists & (at_dst | has_port)
+                requesting = requesting & (busy_dec[cur * OUT + out_port] < 2)
+                # NOTE: scatter/gather OOB markers must be POSITIVE out-of-range —
+                # negative indices wrap NumPy-style in jnp .at[] even with
+                # mode='drop'.
+                OOB_OUT = jnp.int32(S * OUT + 1)
+                req_out = jnp.where(requesting, cur * OUT + out_port, OOB_OUT)
+
+            with jax.named_scope("arbitrate"):
+                # iterative random arbitration (2x internal speedup)
+                # Round 1: every head requests its best port; one random winner
+                # per output.  Round 2 (separable-allocator iteration + the
+                # paper's 2x crossbar speedup): losers re-route to their best
+                # port that still has output tokens, enabling a second grant per
+                # cycle per output.  The `busy` token bucket keeps sustained
+                # link rate at 1 pkt/time.  Each round runs through the
+                # configured arbiter backend (lax scatter-min or the per-switch
+                # Pallas kernel — bit-exact).
+                won1, g1 = arbitrate(req_out, packed)
+
+                qi_best1 = jnp.take_along_axis(qi_down, best[:, None], 1)[:, 0]
+                arr1 = jnp.zeros(NQ, dtype=I32).at[
+                    jnp.where(won1 & ~at_dst, qi_best1, NQ + 1)
+                ].add(1, mode="drop")
+                tokens = (2 - busy_dec) - g1                    # remaining slots
+
+                loser = requesting & ~won1
+                # re-route: best legal port with tokens left and downstream room
+                # (accounting for the round-1 arrival into the same queue)
+                tok_net = tokens[cur[:, None] * OUT + jnp.arange(q * n)[None, :]] > 0
+                room_2 = qlen[qi_down] + arr1[qi_down] < CAP
+                cost2 = jnp.where(legal & tok_net & room_2, cost, BIGCOST)
+                best2 = jnp.argmin(cost2, axis=1).astype(I32)
+                has2 = jnp.take_along_axis(cost2, best2[:, None], 1)[:, 0] < BIGCOST
+                ej_ok = at_dst & (tokens[cur * OUT + q * n + dof] > 0)
+                out2 = jnp.where(at_dst, q * n + dof, best2)
+                req2 = loser & jnp.where(at_dst, ej_ok, has2)
+                req_out2 = jnp.where(req2, cur * OUT + out2, OOB_OUT)
+                won2, g2 = arbitrate(req_out2, packed)
+                won = won1 | won2
+
+                # final chosen queue / minimality per winner
+                qi_best = jnp.where(
+                    won2,
+                    jnp.take_along_axis(
+                        qi_down, jnp.minimum(best2, q * n - 1)[:, None], 1
+                    )[:, 0],
+                    qi_best1,
                 )
-                legal = (
-                    (base & (is_min | (der[:, None] > reserve)) | escalate)
-                    & room & avail_net
+                bmin = jnp.where(
+                    won2,
+                    jnp.take_along_axis(
+                        is_min, jnp.minimum(best2, q * n - 1)[:, None], 1
+                    )[:, 0],
+                    best_min,
                 )
-            else:
-                # minimal-only (min / val / ugal): when every minimal port of
-                # this switch is dead, escalate to budget-bounded deroutes so
-                # packets can round the fault (hops stay inside the VC budget)
-                is_min_h = is_min & healthy
-                escalate = (
-                    ~is_min_h.any(axis=1, keepdims=True)
-                    & unaligned & not_self & healthy & (der[:, None] > 0)
-                )
-                legal = (is_min_h | escalate) & room & avail_net
-            cost = occ * 8 + PEN * (~is_min) + jitter
-            cost = jnp.where(legal, cost, BIGCOST)
-            best = jnp.argmin(cost, axis=1).astype(I32)     # (H,)
-            best_cost = jnp.take_along_axis(cost, best[:, None], 1)[:, 0]
-            has_port = best_cost < BIGCOST
-            best_min = jnp.take_along_axis(is_min, best[:, None], 1)[:, 0]
-
-            out_port = jnp.where(at_dst, q * n + dof, best)
-            requesting = exists & (at_dst | has_port)
-            requesting = requesting & (busy_dec[cur * OUT + out_port] < 2)
-            # NOTE: scatter/gather OOB markers must be POSITIVE out-of-range —
-            # negative indices wrap NumPy-style in jnp .at[] even with
-            # mode='drop'.
-            OOB_OUT = jnp.int32(S * OUT + 1)
-            req_out = jnp.where(requesting, cur * OUT + out_port, OOB_OUT)
-
-            # --------- iterative random arbitration (2x internal speedup) --
-            # Round 1: every head requests its best port; one random winner
-            # per output.  Round 2 (separable-allocator iteration + the
-            # paper's 2x crossbar speedup): losers re-route to their best
-            # port that still has output tokens, enabling a second grant per
-            # cycle per output.  The `busy` token bucket keeps sustained
-            # link rate at 1 pkt/time.  Each round runs through the
-            # configured arbiter backend (lax scatter-min or the per-switch
-            # Pallas kernel — bit-exact).
-            won1, g1 = arbitrate(req_out, packed)
-
-            qi_best1 = jnp.take_along_axis(qi_down, best[:, None], 1)[:, 0]
-            arr1 = jnp.zeros(NQ, dtype=I32).at[
-                jnp.where(won1 & ~at_dst, qi_best1, NQ + 1)
-            ].add(1, mode="drop")
-            tokens = (2 - busy_dec) - g1                    # remaining slots
-
-            loser = requesting & ~won1
-            # re-route: best legal port with tokens left and downstream room
-            # (accounting for the round-1 arrival into the same queue)
-            tok_net = tokens[cur[:, None] * OUT + jnp.arange(q * n)[None, :]] > 0
-            room_2 = qlen[qi_down] + arr1[qi_down] < CAP
-            cost2 = jnp.where(legal & tok_net & room_2, cost, BIGCOST)
-            best2 = jnp.argmin(cost2, axis=1).astype(I32)
-            has2 = jnp.take_along_axis(cost2, best2[:, None], 1)[:, 0] < BIGCOST
-            ej_ok = at_dst & (tokens[cur * OUT + q * n + dof] > 0)
-            out2 = jnp.where(at_dst, q * n + dof, best2)
-            req2 = loser & jnp.where(at_dst, ej_ok, has2)
-            req_out2 = jnp.where(req2, cur * OUT + out2, OOB_OUT)
-            won2, g2 = arbitrate(req_out2, packed)
-            won = won1 | won2
-
-            # final chosen queue / minimality per winner
-            qi_best = jnp.where(
-                won2,
-                jnp.take_along_axis(
-                    qi_down, jnp.minimum(best2, q * n - 1)[:, None], 1
-                )[:, 0],
-                qi_best1,
-            )
-            bmin = jnp.where(
-                won2,
-                jnp.take_along_axis(
-                    is_min, jnp.minimum(best2, q * n - 1)[:, None], 1
-                )[:, 0],
-                best_min,
-            )
-            # per-winner escalation flag + round-1 arrival count into the
-            # winner's queue (the only arr1 value downstream code needs)
-            chosen = jnp.minimum(jnp.where(won2, best2, best), q * n - 1)
-            esc_chosen = jnp.take_along_axis(escalate, chosen[:, None], 1)[:, 0]
-            arr1_tgt = arr1[qi_best]
+                # per-winner escalation flag + round-1 arrival count into the
+                # winner's queue (the only arr1 value downstream code needs)
+                chosen = jnp.minimum(jnp.where(won2, best2, best), q * n - 1)
+                esc_chosen = jnp.take_along_axis(escalate, chosen[:, None], 1)[:, 0]
+                arr1_tgt = arr1[qi_best]
             return won, won2, qi_best, bmin, esc_chosen, arr1_tgt, g1, g2
 
         if fused_route is not None:
-            # ---------- fused route+arbitrate megakernel (one pallas_call,
-            # gridded per switch; candidate masks, cost, argmin and both
-            # arbitration rounds stay VMEM-resident — bit-exact) ----------
-            (won, won2, qi_best, best_min, esc_chosen, arr1_tgt, g1, g2) = (
-                fused_route(
-                    exists, at_dst, dof, der, vcn, route_dsw, link_ok_t,
-                    n_dead_t, qlen, port_occ, busy_dec, jitter, packed,
-                )
+            with jax.named_scope("route_arbitrate"):
+                # fused route+arbitrate megakernel (one pallas_call,
+                # gridded per switch; candidate masks, cost, argmin and both
+                # arbitration rounds stay VMEM-resident — bit-exact)
+                (won, won2, qi_best, best_min, esc_chosen, arr1_tgt, g1, g2) = (
+                    fused_route(
+                        exists, at_dst, dof, der, vcn, route_dsw, link_ok_t,
+                        n_dead_t, qlen, port_occ, busy_dec, jitter, packed,
+                    )
             )
         else:
             (won, won2, qi_best, best_min, esc_chosen, arr1_tgt, g1, g2) = (
                 route_arbitrate_lax()
             )
 
-        # output token update: +1 per grant (burst absorbed by 2x speedup)
-        busy = busy_dec + g1 + g2
+        with jax.named_scope("dequeue"):
+            # output token update: +1 per grant (burst absorbed by 2x speedup)
+            busy = busy_dec + g1 + g2
 
-        # ---------------- dequeue winners ----------------------------------
-        qhead = jnp.where(won, (qhead + 1) % CAP, qhead)
-        dlen = jnp.zeros(NQ, dtype=I32).at[jnp.arange(H)].add(-won.astype(I32))
+            # dequeue winners
+            qhead = jnp.where(won, (qhead + 1) % CAP, qhead)
+            dlen = jnp.zeros(NQ, dtype=I32).at[jnp.arange(H)].add(-won.astype(I32))
 
-        # ---------------- deliveries (ejection winners) --------------------
-        eject = won & at_dst
-        rank = state.f_rank[slot]
-        pstep = state.f_step[slot]
-        src_finite = wt.finite[rank]
-        # sender-side accounting row (infinite sources -> trash row R)
-        send_row = jnp.where(src_finite, rank, R)
-        OOB_RT = jnp.int32((R + 1) * T + 1)
-        sent = state.sent.at[
-            jnp.where(eject, send_row * T + pstep, OOB_RT)
-        ].add(1, mode="drop")
-        drank = wt.ep_rank[dst].astype(I32)
-        drank_ok = (drank >= 0) & wt.finite[jnp.maximum(drank, 0)]
-        recv_row = jnp.where(drank_ok, drank, R)
-        got = state.got.at[
-            jnp.where(eject, recv_row * T + pstep, OOB_RT)
-        ].add(1, mode="drop")
-        tgt_del = eject & src_finite
-        lat_pkt = (t - state.f_birth[slot]).astype(jnp.float32)
-        lat_add = jnp.sum(jnp.where(tgt_del, lat_pkt, 0.0))
-        lat_sum = state.lat_sum + lat_add
-        hop_sum = state.hop_sum + jnp.sum(jnp.where(tgt_del, hop, 0))
-        n_delivered = state.n_delivered + jnp.sum(tgt_del)
-        # every ejection bounds the VC invariant, background included
-        hop_max = jnp.maximum(
-            state.hop_max, jnp.max(jnp.where(eject, hop, 0))
-        )
-
-        # ---------------- network moves (enqueue downstream) ---------------
-        net = won & ~at_dst
-        # re-escalation accounting: moves granted through the forced
-        # fault-escape candidate set (the port the winner took was only
-        # legal because every minimal port was dead / reserve was spent)
-        esc_count = state.esc_count + jnp.sum(net & esc_chosen)
-        tgt_qi = qi_best
-        # ring tail = head_pre + len_pre, invariant under same-cycle dequeue;
-        # a round-2 arrival lands one slot behind the round-1 arrival.
-        tgt_slot = (
-            state.qhead[tgt_qi] + qlen[tgt_qi]
-            + jnp.where(won2, arr1_tgt, 0)
-        ) % CAP
-        tgt_flat = jnp.where(net, tgt_qi * CAP + tgt_slot, OOB)
-        f_dst = state.f_dst.at[tgt_flat].set(dst, mode="drop")
-        f_der = state.f_der.at[tgt_flat].set(der - (~best_min), mode="drop")
-        f_hop = state.f_hop.at[tgt_flat].set(hop + 1, mode="drop")
-        f_rank = state.f_rank.at[tgt_flat].set(rank, mode="drop")
-        f_step = state.f_step.at[tgt_flat].set(pstep, mode="drop")
-        f_birth = state.f_birth.at[tgt_flat].set(state.f_birth[slot], mode="drop")
-        if use_imd:
-            # a packet leaving its intermediate switch enters phase 2
-            f_imd = state.f_imd.at[tgt_flat].set(
-                jnp.where(imd == cur, NOMID, imd), mode="drop"
-            )
-        else:
-            f_imd = state.f_imd
-        dlen = dlen.at[jnp.where(net, tgt_qi, NQ + 1)].add(1, mode="drop")
-
-        # ---------------- step-engine: completion pointers ------------------
-        # a rank is done after its *real* n_steps (padded steps never walked)
-        completed = state.completed
-        for _ in range(4):
-            pidx = jnp.arange(R, dtype=I32) * T + jnp.minimum(completed, T - 1)
-            comp = (completed >= wt.n_steps) | (
-                (sent[pidx] >= wt.total_sends[pidx])
-                & (got[pidx] >= wt.recv_need[pidx])
-            )
-            completed = completed + (
-                wt.finite & (completed < wt.n_steps) & comp
+        with jax.named_scope("deliver"):
+            # deliveries (ejection winners)
+            eject = won & at_dst
+            rank = state.f_rank[slot]
+            pstep = state.f_step[slot]
+            src_finite = wt.finite[rank]
+            # sender-side accounting row (infinite sources -> trash row R)
+            send_row = jnp.where(src_finite, rank, R)
+            OOB_RT = jnp.int32((R + 1) * T + 1)
+            sent = state.sent.at[
+                jnp.where(eject, send_row * T + pstep, OOB_RT)
+            ].add(1, mode="drop")
+            drank = wt.ep_rank[dst].astype(I32)
+            drank_ok = (drank >= 0) & wt.finite[jnp.maximum(drank, 0)]
+            recv_row = jnp.where(drank_ok, drank, R)
+            got = state.got.at[
+                jnp.where(eject, recv_row * T + pstep, OOB_RT)
+            ].add(1, mode="drop")
+            tgt_del = eject & src_finite
+            lat_pkt = (t - state.f_birth[slot]).astype(jnp.float32)
+            lat_add = jnp.sum(jnp.where(tgt_del, lat_pkt, 0.0))
+            lat_sum = state.lat_sum + lat_add
+            hop_sum = state.hop_sum + jnp.sum(jnp.where(tgt_del, hop, 0))
+            n_delivered = state.n_delivered + jnp.sum(tgt_del)
+            # every ejection bounds the VC invariant, background included
+            hop_max = jnp.maximum(
+                state.hop_max, jnp.max(jnp.where(eject, hop, 0))
             )
 
-        # skip empty (padded) steps
-        cs = state.cur_step
-        cs_deg = wt.deg[jnp.arange(R), jnp.minimum(cs, T - 1)]
-        cs = cs + (wt.finite & (cs < wt.n_steps) & (cs_deg == 0))
-
-        # ---------------- injection ----------------------------------------
-        r_of_e = wt.ep_rank.astype(I32)                     # (E,)
-        r_safe = jnp.maximum(r_of_e, 0)
-        e_fin = wt.finite[r_safe]
-        e_cs = jnp.where(e_fin, cs[r_safe], 0)
-        e_di = jnp.where(e_fin, state.dst_i[r_safe], 0)
-        e_pk = jnp.where(e_fin, state.pkt_i[r_safe], 0)
-        flat_td = jnp.minimum(e_cs, T - 1) * MAXD + e_di
-        e_deg = wt.deg[r_safe, jnp.minimum(e_cs, T - 1)]
-        e_np = wt.npkts[r_safe, flat_td]
-        e_ns = wt.n_steps[r_safe]
-        in_window = e_cs < jnp.minimum(e_ns, completed[r_safe] + wt.window[r_safe])
-        has_work = jnp.where(
-            e_fin, (e_cs < e_ns) & (e_di < e_deg) & in_window, True
-        )
-        has_work = has_work & (t >= wt.start_t[r_safe])
-        inj_qi = inj_base + wt.pool[r_safe].astype(I32) * V
-        has_room = qlen[inj_qi] + dlen[inj_qi] < CAP  # dlen: arrivals this cycle
-        do_inj = (r_of_e >= 0) & has_work & has_room
-
-        d_fixed = wt.sends_dst[r_safe, flat_td].astype(I32)
-        rspan = jnp.maximum(wt.smp_hi[r_safe, flat_td] - wt.smp_lo[r_safe, flat_td], 1)
-        rnd = jax.random.bits(k_smp, (E,), dtype=U32)
-        d_smp = wt.smp_lo[r_safe, flat_td] + (rnd % rspan.astype(U32)).astype(I32)
-        d_rank = jnp.where(wt.sampled[r_safe, flat_td], d_smp, d_fixed)
-        d_rank = jnp.clip(d_rank, 0, R - 1)
-        d_ep = wt.rank_ep[d_rank].astype(I32)
-
-        inj_flat = jnp.where(
-            do_inj, inj_qi * CAP + (state.qhead[inj_qi] + qlen[inj_qi]) % CAP,
-            OOB,
-        )
-        f_dst = f_dst.at[inj_flat].set(d_ep, mode="drop")
-        f_der = f_der.at[inj_flat].set(jnp.int32(m), mode="drop")
-        f_hop = f_hop.at[inj_flat].set(0, mode="drop")
-        f_rank = f_rank.at[inj_flat].set(r_safe, mode="drop")
-        f_step = f_step.at[inj_flat].set(jnp.where(e_fin, e_cs, 0), mode="drop")
-        f_birth = f_birth.at[inj_flat].set(t, mode="drop")
-        if use_imd:
-            # Valiant intermediate: one uniform draw per packet from the
-            # healthy pool carried in the workload tables (mid_pool/n_mid
-            # are device data — seeds and fault grids vmap, no retracing)
-            rmid = jax.random.bits(k_mid, (E,), dtype=U32)
-            span = jnp.maximum(n_mid_t, 1).astype(U32)
-            mid = mid_pool_t[(rmid % span).astype(I32)].astype(I32)
-            if policy.adaptive_injection:
-                # UGAL-L: best minimal port vs best port toward the
-                # sampled intermediate, weighted by path length, using
-                # the same port_occ congestion signal as in-network cost
-                csrc = coords[ep_sw]                        # (E, q)
-                cde = coords[d_ep // conc]
-                cme = coords[mid]
-                src_d = csrc[:, port_dim]                   # (E, q*n)
-                unal_d = src_d != cde[:, port_dim]
-                unal_m = src_d != cme[:, port_dim]
-                min_d = (port_val[None, :] == cde[:, port_dim]) & unal_d
-                min_m = (port_val[None, :] == cme[:, port_dim]) & unal_m
-                occ_e = port_occ[
-                    nbr[ep_sw].astype(I32) * IN + in_port_at_nb[ep_sw]
-                ]
-                ok_e = link_ok_t[ep_sw]
-                # a dead/empty candidate set prices as BIGOCC, small enough
-                # that BIGOCC * h_val stays inside int32 for any q
-                BIGOCC = jnp.int32(1 << 24)
-                occ_min = jnp.min(
-                    jnp.where(min_d & ok_e, occ_e, BIGOCC), axis=1
+        with jax.named_scope("move"):
+            # network moves (enqueue downstream)
+            net = won & ~at_dst
+            # re-escalation accounting: moves granted through the forced
+            # fault-escape candidate set (the port the winner took was only
+            # legal because every minimal port was dead / reserve was spent)
+            esc_count = state.esc_count + jnp.sum(net & esc_chosen)
+            tgt_qi = qi_best
+            # ring tail = head_pre + len_pre, invariant under same-cycle dequeue;
+            # a round-2 arrival lands one slot behind the round-1 arrival.
+            tgt_slot = (
+                state.qhead[tgt_qi] + qlen[tgt_qi]
+                + jnp.where(won2, arr1_tgt, 0)
+            ) % CAP
+            tgt_flat = jnp.where(net, tgt_qi * CAP + tgt_slot, OOB)
+            f_dst = state.f_dst.at[tgt_flat].set(dst, mode="drop")
+            f_der = state.f_der.at[tgt_flat].set(der - (~best_min), mode="drop")
+            f_hop = state.f_hop.at[tgt_flat].set(hop + 1, mode="drop")
+            f_rank = state.f_rank.at[tgt_flat].set(rank, mode="drop")
+            f_step = state.f_step.at[tgt_flat].set(pstep, mode="drop")
+            f_birth = state.f_birth.at[tgt_flat].set(state.f_birth[slot], mode="drop")
+            if use_imd:
+                # a packet leaving its intermediate switch enters phase 2
+                f_imd = state.f_imd.at[tgt_flat].set(
+                    jnp.where(imd == cur, NOMID, imd), mode="drop"
                 )
-                occ_val = jnp.min(
-                    jnp.where(min_m & ok_e, occ_e, BIGOCC), axis=1
+            else:
+                f_imd = state.f_imd
+            dlen = dlen.at[jnp.where(net, tgt_qi, NQ + 1)].add(1, mode="drop")
+
+        with jax.named_scope("complete"):
+            # step-engine: completion pointers
+            # a rank is done after its *real* n_steps (padded steps never walked)
+            completed = state.completed
+            for _ in range(4):
+                pidx = jnp.arange(R, dtype=I32) * T + jnp.minimum(completed, T - 1)
+                comp = (completed >= wt.n_steps) | (
+                    (sent[pidx] >= wt.total_sends[pidx])
+                    & (got[pidx] >= wt.recv_need[pidx])
                 )
-                h_min = jnp.sum(csrc != cde, axis=1)
-                h_val = (
-                    jnp.sum(csrc != cme, axis=1)
-                    + jnp.sum(cme != cde, axis=1)
+                completed = completed + (
+                    wt.finite & (completed < wt.n_steps) & comp
                 )
-                take_val = occ_val * h_val < occ_min * h_min
-                mid = jnp.where(take_val, mid, NOMID)
-            f_imd = f_imd.at[inj_flat].set(mid, mode="drop")
-        dlen = dlen.at[jnp.where(do_inj, inj_qi, NQ + 1)].add(1, mode="drop")
-        n_injected = state.n_injected + jnp.sum(do_inj)
 
-        # cursor advance for finite injecting ranks
-        adv = do_inj & e_fin
-        pk2 = jnp.where(adv, e_pk + 1, e_pk)
-        move_d = adv & (pk2 >= e_np)
-        di2 = jnp.where(move_d, e_di + 1, e_di)
-        pk2 = jnp.where(move_d, 0, pk2)
-        move_s = move_d & (di2 >= e_deg)
-        cs2 = jnp.where(move_s, e_cs + 1, e_cs)
-        di2 = jnp.where(move_s, 0, di2)
-        # scatter back to rank arrays (each finite rank has exactly 1 endpoint)
-        upd = jnp.where((r_of_e >= 0) & e_fin, r_of_e, R + 5)
-        cur_step = cs.at[upd].set(cs2, mode="drop")
-        dst_i = state.dst_i.at[upd].set(di2, mode="drop")
-        pkt_i = state.pkt_i.at[upd].set(pk2, mode="drop")
+            # skip empty (padded) steps
+            cs = state.cur_step
+            cs_deg = wt.deg[jnp.arange(R), jnp.minimum(cs, T - 1)]
+            cs = cs + (wt.finite & (cs < wt.n_steps) & (cs_deg == 0))
 
-        # per-epoch delivered / injected counters (epoch 0 on the static path)
-        epoch_delivered = state.epoch_delivered.at[ei].add(jnp.sum(tgt_del))
-        epoch_injected = state.epoch_injected.at[ei].add(jnp.sum(do_inj))
+        with jax.named_scope("inject"):
+            r_of_e = wt.ep_rank.astype(I32)                     # (E,)
+            r_safe = jnp.maximum(r_of_e, 0)
+            e_fin = wt.finite[r_safe]
+            e_cs = jnp.where(e_fin, cs[r_safe], 0)
+            e_di = jnp.where(e_fin, state.dst_i[r_safe], 0)
+            e_pk = jnp.where(e_fin, state.pkt_i[r_safe], 0)
+            flat_td = jnp.minimum(e_cs, T - 1) * MAXD + e_di
+            e_deg = wt.deg[r_safe, jnp.minimum(e_cs, T - 1)]
+            e_np = wt.npkts[r_safe, flat_td]
+            e_ns = wt.n_steps[r_safe]
+            in_window = e_cs < jnp.minimum(e_ns, completed[r_safe] + wt.window[r_safe])
+            has_work = jnp.where(
+                e_fin, (e_cs < e_ns) & (e_di < e_deg) & in_window, True
+            )
+            has_work = has_work & (t >= wt.start_t[r_safe])
+            inj_qi = inj_base + wt.pool[r_safe].astype(I32) * V
+            has_room = qlen[inj_qi] + dlen[inj_qi] < CAP  # dlen: arrivals this cycle
+            do_inj = (r_of_e >= 0) & has_work & has_room
 
-        new_state = SimState(
-            t=t + 1, key=state.key,
-            f_dst=f_dst, f_der=f_der, f_hop=f_hop, f_rank=f_rank,
-            f_step=f_step, f_birth=f_birth, f_imd=f_imd,
-            qhead=qhead, qlen=qlen + dlen, busy=busy,
-            cur_step=cur_step, dst_i=dst_i, pkt_i=pkt_i, completed=completed,
-            sent=sent, got=got,
-            lat_sum=lat_sum, n_delivered=n_delivered, n_injected=n_injected,
-            hop_sum=hop_sum, hop_max=hop_max,
-            esc_count=esc_count,
-            epoch_delivered=epoch_delivered, epoch_injected=epoch_injected,
-        )
+            d_fixed = wt.sends_dst[r_safe, flat_td].astype(I32)
+            rspan = jnp.maximum(wt.smp_hi[r_safe, flat_td] - wt.smp_lo[r_safe, flat_td], 1)
+            rnd = jax.random.bits(k_smp, (E,), dtype=U32)
+            d_smp = wt.smp_lo[r_safe, flat_td] + (rnd % rspan.astype(U32)).astype(I32)
+            d_rank = jnp.where(wt.sampled[r_safe, flat_td], d_smp, d_fixed)
+            d_rank = jnp.clip(d_rank, 0, R - 1)
+            d_ep = wt.rank_ep[d_rank].astype(I32)
+
+            inj_flat = jnp.where(
+                do_inj, inj_qi * CAP + (state.qhead[inj_qi] + qlen[inj_qi]) % CAP,
+                OOB,
+            )
+            f_dst = f_dst.at[inj_flat].set(d_ep, mode="drop")
+            f_der = f_der.at[inj_flat].set(jnp.int32(m), mode="drop")
+            f_hop = f_hop.at[inj_flat].set(0, mode="drop")
+            f_rank = f_rank.at[inj_flat].set(r_safe, mode="drop")
+            f_step = f_step.at[inj_flat].set(jnp.where(e_fin, e_cs, 0), mode="drop")
+            f_birth = f_birth.at[inj_flat].set(t, mode="drop")
+            if use_imd:
+                # Valiant intermediate: one uniform draw per packet from the
+                # healthy pool carried in the workload tables (mid_pool/n_mid
+                # are device data — seeds and fault grids vmap, no retracing)
+                rmid = jax.random.bits(k_mid, (E,), dtype=U32)
+                span = jnp.maximum(n_mid_t, 1).astype(U32)
+                mid = mid_pool_t[(rmid % span).astype(I32)].astype(I32)
+                if policy.adaptive_injection:
+                    # UGAL-L: best minimal port vs best port toward the
+                    # sampled intermediate, weighted by path length, using
+                    # the same port_occ congestion signal as in-network cost
+                    csrc = coords[ep_sw]                        # (E, q)
+                    cde = coords[d_ep // conc]
+                    cme = coords[mid]
+                    src_d = csrc[:, port_dim]                   # (E, q*n)
+                    unal_d = src_d != cde[:, port_dim]
+                    unal_m = src_d != cme[:, port_dim]
+                    min_d = (port_val[None, :] == cde[:, port_dim]) & unal_d
+                    min_m = (port_val[None, :] == cme[:, port_dim]) & unal_m
+                    occ_e = port_occ[
+                        nbr[ep_sw].astype(I32) * IN + in_port_at_nb[ep_sw]
+                    ]
+                    ok_e = link_ok_t[ep_sw]
+                    # a dead/empty candidate set prices as BIGOCC, small enough
+                    # that BIGOCC * h_val stays inside int32 for any q
+                    BIGOCC = jnp.int32(1 << 24)
+                    occ_min = jnp.min(
+                        jnp.where(min_d & ok_e, occ_e, BIGOCC), axis=1
+                    )
+                    occ_val = jnp.min(
+                        jnp.where(min_m & ok_e, occ_e, BIGOCC), axis=1
+                    )
+                    h_min = jnp.sum(csrc != cde, axis=1)
+                    h_val = (
+                        jnp.sum(csrc != cme, axis=1)
+                        + jnp.sum(cme != cde, axis=1)
+                    )
+                    take_val = occ_val * h_val < occ_min * h_min
+                    mid = jnp.where(take_val, mid, NOMID)
+                f_imd = f_imd.at[inj_flat].set(mid, mode="drop")
+            dlen = dlen.at[jnp.where(do_inj, inj_qi, NQ + 1)].add(1, mode="drop")
+            n_injected = state.n_injected + jnp.sum(do_inj)
+
+            # cursor advance for finite injecting ranks
+            adv = do_inj & e_fin
+            pk2 = jnp.where(adv, e_pk + 1, e_pk)
+            move_d = adv & (pk2 >= e_np)
+            di2 = jnp.where(move_d, e_di + 1, e_di)
+            pk2 = jnp.where(move_d, 0, pk2)
+            move_s = move_d & (di2 >= e_deg)
+            cs2 = jnp.where(move_s, e_cs + 1, e_cs)
+            di2 = jnp.where(move_s, 0, di2)
+            # scatter back to rank arrays (each finite rank has exactly 1 endpoint)
+            upd = jnp.where((r_of_e >= 0) & e_fin, r_of_e, R + 5)
+            cur_step = cs.at[upd].set(cs2, mode="drop")
+            dst_i = state.dst_i.at[upd].set(di2, mode="drop")
+            pkt_i = state.pkt_i.at[upd].set(pk2, mode="drop")
+
+            # per-epoch delivered / injected counters (epoch 0 on the static path)
+            epoch_delivered = state.epoch_delivered.at[ei].add(jnp.sum(tgt_del))
+            epoch_injected = state.epoch_injected.at[ei].add(jnp.sum(do_inj))
+
+            new_state = SimState(
+                t=t + 1, key=state.key,
+                f_dst=f_dst, f_der=f_der, f_hop=f_hop, f_rank=f_rank,
+                f_step=f_step, f_birth=f_birth, f_imd=f_imd,
+                qhead=qhead, qlen=qlen + dlen, busy=busy,
+                cur_step=cur_step, dst_i=dst_i, pkt_i=pkt_i, completed=completed,
+                sent=sent, got=got,
+                lat_sum=lat_sum, n_delivered=n_delivered, n_injected=n_injected,
+                hop_sum=hop_sum, hop_max=hop_max,
+                esc_count=esc_count,
+                epoch_delivered=epoch_delivered, epoch_injected=epoch_injected,
+            )
         if spec is None:
             return new_state
 
-        # ------------- telemetry probes (enabled engines only) -------------
-        # Pure extra accumulation from this cycle's internal signals; none
-        # of it feeds back into the physics above.  Window index clamps so
-        # cycles past n_windows * window accumulate into the last window.
-        wi = jnp.minimum(t // spec.window, spec.n_windows - 1)
-        net_move = net
-        # fault-epoch probes: a flip is a cycle whose active epoch differs
-        # from the previous cycle's; dead_links samples the directed dead
-        # count of the active mask each cycle
-        if NE == 1:
-            flip = jnp.int32(0)
-        else:
-            ei_prev = (jnp.sum(
-                jnp.maximum(t - 1, 0) >= wt.epoch_start.astype(I32)
-            ) - 1).astype(I32)
-            flip = ((t > 0) & (ei != ei_prev)).astype(I32)
-        dead_now = jnp.sum(~link_ok_t)
-        # per-pool occupancy histogram: one sample of every queue per cycle
-        occ_hist = jnp.zeros(P * (CAP + 1), dtype=I32).at[
-            h_pool.astype(I32) * (CAP + 1) + qlen
-        ].add(1)
-        # log2 ejection-latency bin per delivered target packet
-        lat_bin = jnp.clip(
-            jnp.floor(jnp.log2(jnp.maximum(lat_pkt, 1.0))).astype(I32),
-            0, spec.lat_bins - 1,
-        )
-        tel = TelemetryState(
-            link_util=tel.link_util.at[wi].add((g1 + g2).reshape(S, OUT)),
-            vc_occ=tel.vc_occ.at[wi].add(occ_hist),
-            deroutes=tel.deroutes.at[wi].add(
-                jnp.sum(net_move & ~best_min)
-            ),
-            escalations=tel.escalations.at[wi].add(
-                jnp.sum(net_move & esc_chosen)
-            ),
-            inflight=tel.inflight.at[wi].add(jnp.sum(qlen)),
-            cycles=tel.cycles.at[wi].add(1),
-            injected=tel.injected.at[wi].add(jnp.sum(do_inj)),
-            delivered=tel.delivered.at[wi].add(jnp.sum(tgt_del)),
-            lat_sum=tel.lat_sum.at[wi].add(lat_add),
-            lat_hist=tel.lat_hist.at[
-                jnp.where(tgt_del, lat_bin, spec.lat_bins + 1)
-            ].add(1, mode="drop"),
-            epoch_flips=tel.epoch_flips.at[wi].add(flip),
-            dead_links=tel.dead_links.at[wi].add(dead_now),
-        )
-        return new_state, tel
+        with jax.named_scope("telemetry"):
+            # telemetry probes (enabled engines only)
+            # Pure extra accumulation from this cycle's internal signals; none
+            # of it feeds back into the physics above.  Window index clamps so
+            # cycles past n_windows * window accumulate into the last window.
+            wi = jnp.minimum(t // spec.window, spec.n_windows - 1)
+            net_move = net
+            # fault-epoch probes: a flip is a cycle whose active epoch differs
+            # from the previous cycle's; dead_links samples the directed dead
+            # count of the active mask each cycle
+            if NE == 1:
+                flip = jnp.int32(0)
+            else:
+                ei_prev = (jnp.sum(
+                    jnp.maximum(t - 1, 0) >= wt.epoch_start.astype(I32)
+                ) - 1).astype(I32)
+                flip = ((t > 0) & (ei != ei_prev)).astype(I32)
+            dead_now = jnp.sum(~link_ok_t)
+            # per-pool occupancy histogram: one sample of every queue per cycle
+            occ_hist = jnp.zeros(P * (CAP + 1), dtype=I32).at[
+                h_pool.astype(I32) * (CAP + 1) + qlen
+            ].add(1)
+            # log2 ejection-latency bin per delivered target packet
+            lat_bin = jnp.clip(
+                jnp.floor(jnp.log2(jnp.maximum(lat_pkt, 1.0))).astype(I32),
+                0, spec.lat_bins - 1,
+            )
+            tel = TelemetryState(
+                link_util=tel.link_util.at[wi].add((g1 + g2).reshape(S, OUT)),
+                vc_occ=tel.vc_occ.at[wi].add(occ_hist),
+                deroutes=tel.deroutes.at[wi].add(
+                    jnp.sum(net_move & ~best_min)
+                ),
+                escalations=tel.escalations.at[wi].add(
+                    jnp.sum(net_move & esc_chosen)
+                ),
+                inflight=tel.inflight.at[wi].add(jnp.sum(qlen)),
+                cycles=tel.cycles.at[wi].add(1),
+                injected=tel.injected.at[wi].add(jnp.sum(do_inj)),
+                delivered=tel.delivered.at[wi].add(jnp.sum(tgt_del)),
+                lat_sum=tel.lat_sum.at[wi].add(lat_add),
+                lat_hist=tel.lat_hist.at[
+                    jnp.where(tgt_del, lat_bin, spec.lat_bins + 1)
+                ].add(1, mode="drop"),
+                epoch_flips=tel.epoch_flips.at[wi].add(flip),
+                dead_links=tel.dead_links.at[wi].add(dead_now),
+            )
+            return new_state, tel
 
     return step

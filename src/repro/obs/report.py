@@ -13,10 +13,11 @@ run produced — no simulation is re-run — and renders:
                              from every ``sim.telemetry`` event (the
                              per-strategy heatmap data);
   * ``latency.csv``        — log2 ejection-latency histograms per label;
-  * ``queue_occupancy.csv``— per-pool queue-occupancy histograms per label;
-  * ``device_timeline.csv``— per-grid device timings + ``jax.profiler``
-                             trace locations from a ``benchmarks.perf
-                             --profile`` run.
+  * ``queue_occupancy.csv``— per-pool queue-occupancy histograms per label.
+
+An ``engine.dispatch`` row of the span tables times device calls whole:
+each span covers a dispatch's enqueue and the device run up to its last
+output, and the compile too where its ``new_key`` is true.
 
 Every table is also queryable in-process (:func:`span_rows`,
 :func:`sched_rows`, :func:`telemetry_events`, :func:`link_heatmap_rows`,
@@ -206,31 +207,6 @@ def hottest_links(source, k: int = 5) -> list[dict]:
     return list(source.get("top_links", []))[:k]
 
 
-def device_timeline_rows(events: list[dict]) -> list[dict]:
-    """Per-grid device timelines from a ``benchmarks.perf --profile`` run.
-
-    One row per ``perf.grid_metrics`` event: the headline timings next to
-    the ``xprof`` directory holding the raw ``jax.profiler`` trace for
-    that grid (open it with any perfetto/tensorboard viewer).
-    """
-    rows = []
-    for ev in events:
-        if ev.get("name") != "perf.grid_metrics":
-            continue
-        rows.append({
-            "grid": ev.get("grid", ""),
-            "lanes": ev.get("lanes", ""),
-            "compile_s": ev.get("compile_s", ""),
-            "device_s": ev.get("device_s", ""),
-            "wall_first_s": ev.get("wall_first_s", ""),
-            "wall_repeat_s": ev.get("wall_repeat_s", ""),
-            "cycles_per_s": ev.get("cycles_per_s", ""),
-            "bucket_hit_rate": ev.get("bucket_hit_rate", ""),
-            "xprof": ev.get("xprof", ""),
-        })
-    return rows
-
-
 def latency_rows(events: list[dict]) -> list[dict]:
     rows = []
     for ev in telemetry_events(events):
@@ -297,10 +273,6 @@ def _run_tables(events: list[dict], heading: str = "##") -> list[str]:
     if sched:
         parts.append(f"\n{heading} Scheduler streams (fragmentation & churn)\n")
         parts.append(_md_table(sched))
-    timelines = device_timeline_rows(events)
-    if timelines:
-        parts.append(f"\n{heading} Device timelines (perf profile)\n")
-        parts.append(_md_table(timelines))
     spans = span_rows(events)
     if spans:
         parts.append(f"\n{heading} Span timings\n")
@@ -367,7 +339,6 @@ def write_report(trace_dir: str, out_dir: str | None = None) -> dict[str, str]:
     emit_csv("link_heatmap", link_heatmap_rows)
     emit_csv("latency", latency_rows)
     emit_csv("queue_occupancy", queue_occupancy_rows)
-    emit_csv("device_timeline", device_timeline_rows)
     md = os.path.join(out_dir, "report.md")
     with open(md, "w") as f:
         f.write(render_markdown(manifest, events))

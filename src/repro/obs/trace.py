@@ -15,8 +15,20 @@ The module-level API (:func:`span`, :func:`event`, :func:`counter`,
 :func:`gauge`) routes through one process-global tracer configured with
 :func:`configure` and is **zero-cost when off**: with no tracer active,
 ``span`` returns one shared ``nullcontext`` singleton and the emitters
-return immediately — instrumented hot paths (the engine dispatchers, the
-scheduler event loop) pay a single global load and a falsy check.
+return immediately — the scheduler event loop pays a single global load
+and a falsy check.
+
+Two kinds of span, on two clocks:
+
+  * :func:`span` writes a JSONL span timed on ``time.perf_counter``, and
+    only while a tracer is active.
+  * :func:`stage` is for the engine's host stages (``engine.prepare``,
+    ``engine.stack``, ``engine.dispatch``, ``engine.to_result``).  It
+    always enters ``jax.profiler.TraceAnnotation(name, **attrs)``, which
+    puts the span and its attributes on the profiler's clock, beside the
+    device's operations, whenever a profile is being captured; with no
+    capture that costs about a microsecond and records nothing.  While a
+    tracer is active it also writes the same JSONL span as ``span``.
 """
 
 from __future__ import annotations
@@ -29,6 +41,8 @@ import platform
 import subprocess
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 SCHEMA = 1
 
@@ -182,6 +196,22 @@ def span(name: str, **attrs):
     """A timing span context manager; the shared no-op when tracing is off."""
     t = _tracer
     return _NULL if t is None else t.span(name, **attrs)
+
+
+@contextlib.contextmanager
+def stage(name: str, **attrs):
+    """A span on the profiler's clock, and in the JSONL log when tracing.
+
+    ``attrs`` ride on both: the profiler records them as the event's
+    stats (numbers and strings; a bool reads back as 0/1).
+    """
+    t = _tracer
+    with TraceAnnotation(name, **attrs):
+        if t is None:
+            yield
+        else:
+            with t.span(name, **attrs):
+                yield
 
 
 def event(name: str, **attrs):

@@ -11,11 +11,15 @@ The load-bearing pins:
     invariants (injected = delivered = latency-histogram mass);
   * ``TelemetrySpec`` is part of the ``get_engine`` memo key;
   * the tracer writes parseable JSONL + manifest and the report renders;
-  * tracing off is zero-cost: one shared nullcontext, no allocation.
+  * tracing off is zero-cost: one shared nullcontext, no allocation;
+  * every op of the cycle loop's body lies under one stage scope, and the
+    engine's host stages land on the profiler's clock.
 """
 
+import glob
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -188,6 +192,69 @@ def test_engine_dispatch_spans(tmp_path):
     assert spans and spans[0]["api"] == "run"
     compiles = [e for e in events if e.get("name") == "engine.compile"]
     assert len(compiles) == engine.trace_count == 1
+
+
+STAGES = ("heads", "route", "arbitrate", "dequeue", "deliver", "move",
+          "complete", "inject")
+
+
+@pytest.mark.parametrize("kernel", ["lax", "pallas"])
+def test_cycle_stages_name_every_loop_body_op(kernel):
+    """The core's lowered text tags each op of the loop body with a stage
+    scope: all of the default path's, ``route_arbitrate`` in place of
+    ``route`` and ``arbitrate`` for the fused kernel, and nothing else."""
+    engine = SimEngine(SMALL, mode="omniwar", kernel=kernel)
+    prep = engine.prepare(_a2a("row"))
+    text = engine._run1.lower(prep.tables, np.int32(0), np.int32(8)).as_text(
+        debug_info=True)
+    body = [n for n in re.findall(r'loc\("([^"]*)"', text)
+            if n.startswith("jit(core)/while/body/")]
+    assert body
+    seen = {n.split("/")[3] for n in body}
+    want = set(STAGES)
+    if kernel == "pallas":
+        want = want - {"route", "arbitrate"} | {"route_arbitrate"}
+    assert seen == want
+
+
+def test_engine_stages_on_profiler_clock(tmp_path):
+    """A ``run_grid`` call under the profiler shows the engine's four host
+    stages inside the call, in order; ``new_key`` marks the compile."""
+    from jax import profiler
+
+    engine = SimEngine(SMALL, mode="omniwar")
+    wls = [_a2a("row"), _a2a("diagonal")]
+    obs_trace.disable()
+    options = profiler.ProfileOptions()
+    options.python_tracer_level = 0  # the spans alone; keeps the test short
+    profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for i in range(2):
+            with profiler.TraceAnnotation("test.call", index=i):
+                engine.run_grid(wls, seeds=[0, 1], horizon=2)
+    finally:
+        profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    order = ("engine.prepare", "engine.stack", "engine.dispatch",
+             "engine.to_result")
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+               dict(e.stats) if e.name in order else {})
+              for plane in profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    calls = sorted((s, e) for n, s, e, _ in events if n == "test.call")
+    assert len(calls) == 2
+    for i, (lo, hi) in enumerate(calls):
+        inside = sorted((s, e, n, st) for n, s, e, st in events
+                        if n in order and lo <= s and e <= hi)
+        assert [n for _, _, n, _ in inside] == list(order)
+        assert all(a[1] <= b[0] for a, b in zip(inside, inside[1:]))
+        dispatch = inside[2][3]
+        assert dispatch["new_key"] == (1 if i == 0 else 0)
+        assert dispatch["api"] == "run_grid"
+        assert dispatch["lanes"] == dispatch["padded_lanes"] == 4
+        assert inside[3][3]["arrays"] == 4 * 11
 
 
 def test_span_off_is_shared_nullcontext():
