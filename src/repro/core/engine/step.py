@@ -32,6 +32,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.engine.arb import make_arbiter
 from repro.core.engine.route_kernel import make_fused_router
@@ -108,6 +109,64 @@ def all_done(wt: WorkloadTables, state: SimState) -> jnp.ndarray:
     return jnp.all(jnp.where(wt.finite, state.completed >= wt.n_steps, True))
 
 
+class LinkViews:
+    """Per-link views of what lies behind every head's network ports.
+
+    Heads are switch-major (``H == S * HS``), so a value that depends only
+    on a head's switch and port is one ``(S, q*n)`` row per switch,
+    broadcast over its ``HS`` heads: :meth:`per_switch`.  Port ``p`` of
+    switch ``s`` feeds one link, into in-port ``in_port_at_nb[s, p]`` of
+    switch ``nbr[s, p]``; the ``S * q*n`` link rows are gathered once, with
+    static indices, and broadcast.  Self ports read the switch's own
+    in-port; they are never legal candidates.
+
+    Everything here equals a gather through the head's downstream queue
+    index ``qi_down[h, p] = ((nbr * IN + in_port_at_nb)[sw(h), p] * P +
+    pool(h)) * V + vcn[h]`` (:meth:`down_index`), which the cycle kernel
+    keeps only to enqueue into the chosen port's queue.
+    """
+
+    def __init__(self, st: StaticTables):
+        self.S, self.IN, self.P, self.V = st.S, st.IN, st.P, st.V
+        self.QN, self.HS = st.q * st.n, st.H // st.S
+        self.link_row = (np.asarray(st.nbr, dtype=np.int32) * st.IN
+                         + np.asarray(st.in_port_at_nb, dtype=np.int32)
+                         ).reshape(-1)                    # (S * q*n,)
+        self.h_pool = st.h_pool.astype(I32)
+
+    def per_switch(self, a):
+        """``(S, q*n) -> (H, q*n)``: every head sees its switch's row."""
+        S, HS, QN = self.S, self.HS, self.QN
+        return jnp.broadcast_to(a[:, None, :], (S, HS, QN)).reshape(S * HS, QN)
+
+    def per_link(self, a):
+        """A per-(switch, in-port) vector ``a`` (``(S*IN,)``) behind every
+        head's ports: ``(H, q*n)``."""
+        return self.per_switch(a[self.link_row].reshape(self.S, self.QN))
+
+    def down_index(self, vcn):
+        """``qi_down``: the downstream queue behind every head's ports."""
+        base = (self.link_row * (self.P * self.V)).reshape(self.S, self.QN)
+        return (self.per_switch(jnp.asarray(base))
+                + (self.h_pool * self.V + vcn)[:, None])
+
+    def down_view(self, x, vcn):
+        """A per-queue vector ``x`` (``(NQ,)``) behind every head's ports
+        at its next VC ``vcn``: exactly ``x[down_index(vcn)]``.  The link
+        rows of ``(P, V)`` queues are gathered once; each head takes its
+        pool's row and picks its VC with ``V - 1`` selects."""
+        S, IN, P, V, QN = self.S, self.IN, self.P, self.V, self.QN
+        rows = x.reshape(S * IN, P, V)[self.link_row]
+        # (S, 1, P, 1, q*n, V): each link's queues, aligned with the heads'
+        # (switch, in-port, pool, VC) layout and their ports
+        rows = rows.reshape(S, QN, P, V).transpose(0, 2, 1, 3)[:, None, :, None]
+        vc = vcn.reshape(S, IN, P, V, 1)
+        out = jnp.broadcast_to(rows[..., 0], (S, IN, P, V, QN))
+        for v in range(1, V):
+            out = jnp.where(vc == v, rows[..., v], out)
+        return out.reshape(S * self.HS, QN)
+
+
 def build_step(
     st: StaticTables,
     telemetry: TelemetrySpec | None = None,
@@ -153,6 +212,11 @@ def build_step(
     # (bit-exact — see repro.core.engine.route_kernel); the arb backend is
     # subsumed, since both rounds live inside the fused kernel
     fused_route = make_fused_router(st) if st.kernel == "pallas" else None
+    links = LinkViews(st)
+    # each switch's own coordinate in the dimension of each of its ports
+    own_d = jnp.asarray(
+        np.asarray(coords, dtype=np.int32)[:, np.asarray(port_dim)]
+    )                                                   # (S, q*n)
     BIGCOST = jnp.int32(1 << 28)
     OOB = jnp.int32(NQ * CAP + 5)  # safely out of bounds => dropped scatters
     NOMID = jnp.int32(S)           # f_imd sentinel: no (remaining) intermediate
@@ -231,23 +295,19 @@ def build_step(
         def route_arbitrate_lax():
             with jax.named_scope("route"):
                 # routing: candidate network ports (lax path)
-                ccur = coords[cur]                              # (H, q)
                 cdst = coords[route_dsw]                        # (H, q)
                 pv = port_val[None, :]                          # (1, q*n)
-                cur_d = ccur[:, port_dim]                       # (H, q*n)
-                dst_d = cdst[:, port_dim]
+                cur_d = links.per_switch(own_d)                 # (H, q*n)
+                dst_d = jnp.repeat(cdst, n, axis=1)             # port d*n + v: dim d
                 unaligned = cur_d != dst_d                      # (H, q*n)
                 not_self = pv != cur_d
                 is_min = (pv == dst_d) & unaligned
-                healthy = link_ok_t[cur]                        # (H, q*n) faults
-                nb = nbr[cur].astype(I32)                       # (H, q*n)
-                ipnb = in_port_at_nb[cur].astype(I32)           # (H, q*n)
-                qi_down = ((nb * IN + ipnb) * P + h_pool[:, None]) * V + vcn[:, None]
-                room = qlen[qi_down] < CAP                      # own queue has space
-                occ = port_occ[nb * IN + ipnb]                  # congestion signal
-                avail_net = busy_dec[
-                    cur[:, None] * OUT + jnp.arange(q * n)[None, :]
-                ] < 2
+                healthy = links.per_switch(link_ok_t)           # (H, q*n) faults
+                room = links.down_view(qlen, vcn) < CAP         # own queue has space
+                occ = links.per_link(port_occ)                  # congestion signal
+                avail_net = links.per_switch(
+                    busy_dec.reshape(S, OUT)[:, :q * n]
+                ) < 2
                 if policy.adaptive_deroutes:
                     # Omni-WAR: deroutes in any unaligned dimension while budget
                     # lasts; dead links drop out of the candidate set.  Under
@@ -308,6 +368,7 @@ def build_step(
                 # Pallas kernel — bit-exact).
                 won1, g1 = arbitrate(req_out, packed)
 
+                qi_down = links.down_index(vcn)                 # (H, q*n)
                 qi_best1 = jnp.take_along_axis(qi_down, best[:, None], 1)[:, 0]
                 arr1 = jnp.zeros(NQ, dtype=I32).at[
                     jnp.where(won1 & ~at_dst, qi_best1, NQ + 1)
@@ -317,8 +378,8 @@ def build_step(
                 loser = requesting & ~won1
                 # re-route: best legal port with tokens left and downstream room
                 # (accounting for the round-1 arrival into the same queue)
-                tok_net = tokens[cur[:, None] * OUT + jnp.arange(q * n)[None, :]] > 0
-                room_2 = qlen[qi_down] + arr1[qi_down] < CAP
+                tok_net = links.per_switch(tokens.reshape(S, OUT)[:, :q * n]) > 0
+                room_2 = links.down_view(qlen + arr1, vcn) < CAP
                 cost2 = jnp.where(legal & tok_net & room_2, cost, BIGCOST)
                 best2 = jnp.argmin(cost2, axis=1).astype(I32)
                 has2 = jnp.take_along_axis(cost2, best2[:, None], 1)[:, 0] < BIGCOST
