@@ -1,14 +1,31 @@
 """Resource allocation functions for HyperX networks (paper Section 4).
 
 Each allocation function maps the logical coordinates of a job's rank onto
-physical topology coordinates:
+a physical switch and endpoint offset:
 
-    f(p, r_y, r_x) = (s_y, s_x, c)
+    f(p, r_y, r_x) = (switch coordinates, c)
 
 where ``p`` is the partition identifier, ``r = n*r_y + r_x`` is the linear
-rank inside the partition, ``(s_y, s_x)`` the physical switch and ``c`` the
-endpoint offset within the switch.  On an n x n HyperX with concentration n,
-the machine supports exactly n disjoint partitions of n**2 endpoints each.
+rank inside the partition and ``c`` the endpoint offset within the switch.
+A base block is n**2 endpoints, so a well-balanced q-D HyperX of side n
+holds exactly n**(q-1) disjoint partitions; on the paper's n x n machine
+that is n partitions and the coordinates are ``(s_y, s_x)``.
+
+The paper defines the strategies in 2D.  Their q-D forms are this
+repository's (DESIGN.md §4): write ``p`` in base n as digits
+p_{q-2} .. p_0 (p_0 least significant); switch coordinates are listed
+most significant first, as in :meth:`HyperX.switch_id`; every form
+reduces to the paper's formula at q = 2, bit for bit.
+
+  row:           (p_{q-2}, .., p_0, r_y); r_x       -- a line
+  diagonal:      (r_y, r_y + p_{q-2}, .., r_y + p_0) mod n; r_x
+  full_spread:   (p_{q-2}, .., p_1, r_y, r_x); p_0  -- a plane
+  rectangular:   boxes of side 2 in every dimension but the last and
+                 n / 2**(q-1) in the last
+  l_shape:       the 2D L of partition p_0 in the plane of the last two
+                 dimensions, at leading coordinates (p_{q-2}, .., p_1)
+  random_endpoint, random_switch: a seeded permutation of the machine's
+                 endpoints (switches), indexed by the linear rank
 
 Implemented strategies (names follow the paper):
 
@@ -33,7 +50,8 @@ import numpy as np
 
 from repro.core.hyperx import HyperX
 
-Triplet = Tuple[np.ndarray, np.ndarray, np.ndarray]
+# (switch id, endpoint offset) per rank
+Placement = Tuple[np.ndarray, np.ndarray]
 
 
 # --------------------------------------------------------------------------
@@ -47,86 +65,106 @@ class AllocationStrategy:
     kind: str  # 'linear' | 'tiling' | 'random'
     locality_aware: bool
     convexity: str  # 'convex' | 'weakly-convex' | 'non-convex'
-    # map_block(p, r_y, r_x, n, rng) -> (s_y, s_x, c); vectorized over arrays.
-    map_block: Callable[[np.ndarray, np.ndarray, np.ndarray, int, np.random.Generator], Triplet]
+    # map_block(p, r_y, r_x, n, q, rng) -> (switch, c); vectorized over arrays.
+    map_block: Callable[..., Placement]
     needs_rng: bool = False
 
-    def __call__(self, p, r_y, r_x, n, rng=None):
+    def __call__(self, p, r_y, r_x, n, q=2, rng=None) -> Placement:
         p = np.asarray(p, dtype=np.int64)
         r_y = np.asarray(r_y, dtype=np.int64)
         r_x = np.asarray(r_x, dtype=np.int64)
         if self.needs_rng and rng is None:
             rng = np.random.default_rng(0)
-        return self.map_block(p, r_y, r_x, n, rng)
+        return self.map_block(p, r_y, r_x, n, q, rng)
 
 
-def _row(p, r_y, r_x, n, rng):
+def _digits(p, n, q):
+    """Block id ``p`` as base-n digits (p_{q-2}, .., p_0)."""
+    return [(p // n**i) % n for i in range(q - 2, -1, -1)]
+
+
+def _switch(coords, n):
+    """Linear switch id of coordinates listed most significant first."""
+    s = 0
+    for c in coords:
+        s = s * n + c
+    return s
+
+
+def _row(p, r_y, r_x, n, q, rng):
     # row(p, r_y, r_x) = (p, r_y, r_x): all endpoints in row p.
-    return p % n, r_y % n, r_x % n
+    return _switch([*_digits(p, n, q), r_y % n], n), r_x % n
 
 
-def _full_spread(p, r_y, r_x, n, rng):
-    # full_spread(p, r_y, r_x) = (r_y, r_x, p): one endpoint on EVERY switch.
-    return r_y % n, r_x % n, p % n
+def _full_spread(p, r_y, r_x, n, q, rng):
+    # full_spread(p, r_y, r_x) = (r_y, r_x, p): one endpoint on EVERY
+    # switch (of the plane the leading digits select).
+    *lead, p0 = _digits(p, n, q)
+    return _switch([*lead, r_y % n, r_x % n], n), p0
 
 
-def _diagonal(p, r_y, r_x, n, rng):
+def _diagonal(p, r_y, r_x, n, q, rng):
     # diagonal(p, r_y, r_x) = (r_y, (r_y + p) mod n, r_x): one switch per
-    # row/column -- maximal distance, maximal partition bandwidth among
-    # locality-aware strategies.
-    return r_y % n, (r_y + p) % n, r_x % n
+    # line of the machine -- maximal distance, maximal partition bandwidth
+    # among locality-aware strategies.
+    r_y = r_y % n
+    return _switch([r_y] + [(r_y + d) % n for d in _digits(p, n, q)], n), r_x % n
 
 
-def _rectangular(p, r_y, r_x, n, rng):
+def _rectangular(p, r_y, r_x, n, q, rng):
     # Paper formula (Sec. 4.2):
     #   (rem(r_y,2) + n/2*rem(p,2), quo(r_y,2) + 2*quo(p,2), r_x)
     # As printed this yields OVERLAPPING rectangles (p=0 covers rows {0,1} x
     # cols {0..3}, p=2 covers rows {0,1} x cols {2..5}), contradicting the
     # paper's own claim of n non-overlapping partitions.  Swapping the two
     # offset terms gives the intended disjoint sqrt(n/2) x sqrt(2n) tiling
-    # (2 rows x 4 cols for n=8); erratum recorded in DESIGN.md.
-    if n % 2:
-        raise ValueError("rectangular tessellation requires even n")
-    s_y = (r_y % 2) + 2 * (p // 2)
-    s_x = (r_y // 2) + (n // 2) * (p % 2)
-    return s_y % n, s_x % n, r_x % n
+    # (2 rows x 4 cols for n=8); erratum recorded in DESIGN.md.  In q-D a
+    # box has side 2 in every dimension but the last and n / 2**(q-1) in
+    # the last; box p is mixed radix over the tile counts (last dimension
+    # least significant), and r_y walks the first dimension fastest.
+    if n % 2 ** (q - 1):
+        raise ValueError(
+            f"rectangular tessellation needs n divisible by 2**(q-1), "
+            f"got n={n}, q={q}")
+    sides = [2] * (q - 1) + [n // 2 ** (q - 1)]
+    box = []
+    for side in reversed(sides):
+        box.append(p % (n // side))
+        p = p // (n // side)
+    coords = []
+    for side, b in zip(sides, reversed(box)):
+        coords.append((b * side + r_y % side) % n)
+        r_y = r_y // side
+    return _switch(coords, n), r_x % n
 
 
-def _l_shape(p, r_y, r_x, n, rng):
+def _l_shape(p, r_y, r_x, n, q, rng):
     # Piecewise: a vertical ray anchored at (p, p) plus a horizontal ray.
     #   (p + r_y, p, r_x)                       for r_y <  n//2
     #   (p, p + r_y - n//2 + 1, r_x)            otherwise
-    # Modular arithmetic applies to switch coordinates.
+    # Modular arithmetic applies to switch coordinates.  In q-D the L lies
+    # in the plane of the last two dimensions.
+    *lead, p0 = _digits(p, n, q)
     half = n // 2
     vert = r_y < half
-    s_y = np.where(vert, (p + r_y) % n, p % n)
-    s_x = np.where(vert, p % n, (p + r_y - half + 1) % n)
-    return s_y, s_x, r_x % n
+    s_y = np.where(vert, (p0 + r_y) % n, p0)
+    s_x = np.where(vert, p0, (p0 + r_y - half + 1) % n)
+    return _switch([*lead, s_y, s_x], n), r_x % n
 
 
-def _perm_from_rng(rng: np.random.Generator, size: int) -> np.ndarray:
-    return rng.permutation(size)
+def _random_endpoint(p, r_y, r_x, n, q, rng):
+    # pi is a random permutation of the n**(q+1) endpoint slots (switch,
+    # offset < n); the linear rank index maps straight into it.
+    size = n ** (q + 1)
+    tgt = rng.permutation(size)[(p * n * n + r_y * n + r_x) % size]
+    return tgt // n, tgt % n
 
 
-def _random_endpoint(p, r_y, r_x, n, rng):
-    # pi is a random permutation of the n**3 endpoint triplets; the linear
-    # rank index maps straight into the permuted space.
-    pi = _perm_from_rng(rng, n**3)
-    lin = (p * n * n + r_y * n + r_x) % (n**3)
-    tgt = pi[lin]
-    c = tgt % n
-    s_x = (tgt // n) % n
-    s_y = tgt // (n * n)
-    return s_y, s_x, c
-
-
-def _random_switch(p, r_y, r_x, n, rng):
-    # sigma is a random permutation of the n**2 switches; r_y selects the
+def _random_switch(p, r_y, r_x, n, q, rng):
+    # sigma is a random permutation of the n**q switches; r_y selects the
     # switch, r_x the endpoint offset -> switch locality preserved.
-    sigma = _perm_from_rng(rng, n * n)
-    lin = (p * n + r_y) % (n * n)
-    tgt = sigma[lin]
-    return tgt // n, tgt % n, r_x % n
+    size = n**q
+    return rng.permutation(size)[(p * n + r_y) % size], r_x % n
 
 
 ALLOCATIONS: Dict[str, AllocationStrategy] = {
@@ -178,6 +216,30 @@ class Partition:
         return {int(e): r for r, e in enumerate(self.endpoints)}
 
 
+def num_blocks(topo: HyperX) -> int:
+    """Base blocks of n**2 endpoints on ``topo``: n**(q-1)."""
+    if topo.q < 2:
+        raise ValueError(f"allocation strategies need q >= 2, got {topo}")
+    return topo.n ** (topo.q - 1)
+
+
+def _place(strat: AllocationStrategy, topo: HyperX, job_id: int,
+           blk: np.ndarray, r_in: np.ndarray, seed: int) -> Partition:
+    """Rank ``i`` at rank ``r_in[i]`` of base block ``blk[i]``."""
+    n = topo.n
+    num_blocks(topo)  # refuses q < 2
+    rng = np.random.default_rng(seed) if strat.needs_rng else None
+    sw, c = strat(blk, r_in // n, r_in % n, n, topo.q, rng)
+    return Partition(
+        strategy=strat.name,
+        topo=topo,
+        job_id=job_id,
+        size=len(r_in),
+        endpoints=(sw * topo.concentration + c).astype(np.int64),
+        switches=np.unique(sw).astype(np.int64),
+    )
+
+
 def allocate_partition(
     strategy: str | AllocationStrategy,
     topo: HyperX,
@@ -194,31 +256,15 @@ def allocate_partition(
     from the same permutation and stay disjoint.
     """
     strat = get_strategy(strategy) if isinstance(strategy, str) else strategy
-    n = topo.n
-    block = n * n
+    block = topo.n * topo.n
     if size is None:
         size = block
     if size <= 0 or size > topo.num_endpoints:
         raise ValueError(f"partition size {size} out of range")
     k = -(-size // block)  # blocks needed (ceil)
-    first_block = job_id * k
     ranks = np.arange(size, dtype=np.int64)
-    blk = first_block + ranks // block  # base partition id per rank
-    r_in = ranks % block
-    r_y = r_in // n
-    r_x = r_in % n
-    rng = np.random.default_rng(seed) if strat.needs_rng else None
-    s_y, s_x, c = strat(blk, r_y, r_x, n, rng)
-    endpoints = (s_y * n + s_x) * topo.concentration + c
-    switches = np.unique(s_y * n + s_x)
-    return Partition(
-        strategy=strat.name,
-        topo=topo,
-        job_id=job_id,
-        size=size,
-        endpoints=endpoints.astype(np.int64),
-        switches=switches.astype(np.int64),
-    )
+    return _place(strat, topo, job_id, job_id * k + ranks // block,
+                  ranks % block, seed)
 
 
 def allocate_blocks(
@@ -236,19 +282,20 @@ def allocate_blocks(
     block slots it found free, in rank order.  Rank ``r`` lands in block
     ``block_ids[r // n**2]``; ``size`` (default: all of them) may take a
     prefix of the final block.  All strategies keep distinct block ids in
-    ``[0, n)`` pairwise disjoint, so any subset of slots yields a valid
-    partition.
+    ``[0, n**(q-1))`` pairwise disjoint, so any subset of slots yields a
+    valid partition.
     """
     strat = get_strategy(strategy) if isinstance(strategy, str) else strategy
-    n = topo.n
-    block = n * n
+    block = topo.n * topo.n
+    blocks = num_blocks(topo)
     block_ids = np.asarray(block_ids, dtype=np.int64)
     if block_ids.ndim != 1 or len(block_ids) == 0:
         raise ValueError(f"need a non-empty 1D block list, got {block_ids!r}")
     if len(np.unique(block_ids)) != len(block_ids):
         raise ValueError(f"duplicate block ids in {block_ids.tolist()}")
-    if (block_ids < 0).any() or (block_ids >= n).any():
-        raise ValueError(f"block ids {block_ids.tolist()} out of range [0, {n})")
+    if (block_ids < 0).any() or (block_ids >= blocks).any():
+        raise ValueError(
+            f"block ids {block_ids.tolist()} out of range [0, {blocks})")
     if size is None:
         size = len(block_ids) * block
     if not 0 < size <= len(block_ids) * block:
@@ -256,19 +303,8 @@ def allocate_blocks(
             f"size {size} does not fit {len(block_ids)} blocks of {block}"
         )
     ranks = np.arange(size, dtype=np.int64)
-    blk = block_ids[ranks // block]
-    r_in = ranks % block
-    rng = np.random.default_rng(seed) if strat.needs_rng else None
-    s_y, s_x, c = strat(blk, r_in // n, r_in % n, n, rng)
-    endpoints = (s_y * n + s_x) * topo.concentration + c
-    return Partition(
-        strategy=strat.name,
-        topo=topo,
-        job_id=job_id,
-        size=size,
-        endpoints=endpoints.astype(np.int64),
-        switches=np.unique(s_y * n + s_x).astype(np.int64),
-    )
+    return _place(strat, topo, job_id, block_ids[ranks // block],
+                  ranks % block, seed)
 
 
 def scavenge_partition(
@@ -344,10 +380,10 @@ class JobAllocator:
 
     def allocate(self, size: int | None = None, strategy: str | None = None) -> Partition:
         strat = get_strategy(strategy) if strategy else self.strategy
-        n = self.topo.n
-        block = n * n
+        block = self.topo.n * self.topo.n
         size = size or block
         k = -(-size // block)
+        # n**(q-1) slots on a well-balanced machine
         max_jobs = self.topo.num_endpoints // (k * block)
         for slot in range(max_jobs):
             part = allocate_partition(strat, self.topo, slot, size, self.seed)

@@ -304,6 +304,12 @@ class SimEngine:
             "hit_rate": (self.bucket_hits / total) if total else 0.0,
         }
 
+    @property
+    def heads_per_lane(self) -> int:
+        """Queue heads the cycle loop serves per lane and cycle: H = S *
+        IN * P * V, the length of every per-head array of the step."""
+        return self.static.H
+
     # ------------------------------------------------------------ running
     def run(
         self,
@@ -568,6 +574,7 @@ class SimEngine:
             "engine.dispatch", api=api, mode=self.mode, lanes=lanes,
             padded_lanes=int(np.prod(dims)), bucket=str(bucket),
             new_key=new_key, backend=self.lane_backend,
+            heads=self.heads_per_lane, switches=self.static.S,
         ):
             outs = jax.block_until_ready(
                 runner(tables, seeds, jnp.int32(horizon))

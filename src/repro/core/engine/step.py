@@ -36,7 +36,7 @@ import numpy as np
 
 from repro.core.engine.arb import make_arbiter
 from repro.core.engine.route_kernel import make_fused_router
-from repro.core.engine.tables import StaticTables
+from repro.core.engine.tables import HEAD_BITS, StaticTables
 from repro.core.engine.workload_tables import WorkloadTables
 from repro.obs.probes import TelemetrySpec, TelemetryState
 from repro.route import get_policy
@@ -289,8 +289,8 @@ def build_step(
             busy_dec = jnp.maximum(state.busy - 1, 0)           # link served 1 pkt
             vcn = jnp.minimum(hop + 1, V - 1)                   # (H,) next VC
             jitter = jax.random.randint(k_jit, (H, q * n), 0, 8, dtype=I32)
-            arb_key = jax.random.bits(k_arb, (H,), dtype=U32) >> 17  # 15 bits
-            packed = (arb_key << 17) | jnp.arange(H, dtype=U32)
+            arb_key = jax.random.bits(k_arb, (H,), dtype=U32) >> HEAD_BITS
+            packed = (arb_key << HEAD_BITS) | jnp.arange(H, dtype=U32)
 
         def route_arbitrate_lax():
             with jax.named_scope("route"):

@@ -31,6 +31,10 @@ from repro.core.engine.packing import pack
 from repro.core.hyperx import HyperX
 from repro.route import get_policy, neighbor_tables, port_layout
 
+# low bits of the packed arbitration key that hold the head index; the
+# random draw fills the 32 - HEAD_BITS above them (step.py)
+HEAD_BITS = 17
+
 I32 = jnp.int32
 
 
@@ -114,6 +118,11 @@ def build_static_tables(
     V = policy.vc_budget(q, m)  # hop-indexed VCs (deadlock freedom)
     NQ = S * IN * P * V
     H = NQ                     # one potential head per queue
+    if H >= 1 << HEAD_BITS:
+        raise ValueError(
+            f"{H} queue heads per lane do not fit the {HEAD_BITS}-bit head "
+            f"field of the packed arbitration key (random bits << "
+            f"{HEAD_BITS} | head): {topo}, mode={mode!r}, V={V}, P={P}")
 
     coords_np = topo.all_switch_coords()                       # (S, q)
     nbr, in_port_at_nb = neighbor_tables(coords_np, n, q)

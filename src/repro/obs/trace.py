@@ -150,9 +150,11 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
+        """A timed ``span`` event; yields its attributes, and what is added
+        to them before the span ends is logged with it."""
         t0 = time.perf_counter()
         try:
-            yield self
+            yield attrs
         finally:
             self.event(name, type="span",
                        dur_s=round(time.perf_counter() - t0, 6), **attrs)
@@ -203,15 +205,19 @@ def stage(name: str, **attrs):
     """A span on the profiler's clock, and in the JSONL log when tracing.
 
     ``attrs`` ride on both: the profiler records them as the event's
-    stats (numbers and strings; a bool reads back as 0/1).
+    stats (numbers and strings; a bool reads back as 0/1).  The context
+    yields ``note(**more)``, which adds attributes known only once the
+    work inside has run.
     """
     t = _tracer
-    with TraceAnnotation(name, **attrs):
-        if t is None:
-            yield
-        else:
-            with t.span(name, **attrs):
-                yield
+    with TraceAnnotation(name, **attrs) as ann, \
+            (_NULL if t is None else t.span(name, **attrs)) as logged:
+        def note(**more):
+            ann.set_metadata(**more)
+            if logged is not None:
+                logged.update(more)
+
+        yield note
 
 
 def event(name: str, **attrs):

@@ -85,6 +85,12 @@ class BlockLedger:
                 f"block ledger needs a well-balanced machine "
                 f"(concentration == n), got {topo}"
             )
+        if topo.q != 2:
+            raise ValueError(
+                f"block ledger's slot views assume the n x n machine's n "
+                f"block slots; a q={topo.q} HyperX ({topo}) is not "
+                f"supported yet"
+            )
         if policy not in ("first_fit", "best_fit"):
             raise ValueError(f"unknown placement policy {policy!r}")
         self.topo = topo

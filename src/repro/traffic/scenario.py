@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core.allocation import Partition, allocate_partition
 from repro.core.hyperx import HyperX
+from repro.obs import trace as obs_trace
 from repro.traffic.base import AppTraffic, build_phases, get_pattern
 from repro.traffic.workload import Workload, background_noise, compose_workload
 
@@ -104,7 +105,12 @@ def _resolve_placement(
         return spec.placement
     job_id = strategy_counts.get(spec.placement, 0)
     strategy_counts[spec.placement] = job_id + 1
-    return allocate_partition(spec.placement, topo, job_id, size=spec.ranks)
+    with obs_trace.stage("alloc.place", strategy=spec.placement,
+                         q=topo.q) as note:
+        part = allocate_partition(spec.placement, topo, job_id,
+                                  size=spec.ranks)
+        note(ranks=part.size, switches=len(part.switches))
+    return part
 
 
 def build_app(spec: AppSpec, part: Partition, default_seed: int) -> AppTraffic:

@@ -101,3 +101,19 @@ def test_engine_pallas_arb_bit_identical():
                         arb="pallas")
     assert pal_val.run(wl, seed=1, horizon=5000) == lax_val.run(
         wl, seed=1, horizon=5000)
+
+
+def test_packed_key_head_field_guard():
+    """Engine tables refuse a machine whose heads per lane overflow the
+    17-bit head field of the packed key (the index would spill into the
+    random bits and arbitration would go wrong without an error)."""
+    from repro.core.engine.tables import HEAD_BITS, build_static_tables
+
+    topo = HyperX(n=8, q=3)
+    st = build_static_tables(topo, mode="omniwar")
+    assert st.H == 512 * 32 * 7 < 1 << HEAD_BITS
+    for mode in ("val", "ugal"):
+        with pytest.raises(ValueError, match="17-bit head field"):
+            build_static_tables(topo, mode=mode)
+    with pytest.raises(ValueError, match="17-bit head field"):
+        SimEngine(topo, mode="omniwar", max_deroutes=6)  # V = 10

@@ -194,6 +194,35 @@ def test_engine_dispatch_spans(tmp_path):
     assert len(compiles) == engine.trace_count == 1
 
 
+def test_placement_and_machine_size_in_the_trace(tmp_path):
+    """``alloc.place`` wraps each strategy-name placement of a scenario
+    (strategy, q, ranks, distinct switches touched); ``engine.dispatch``
+    carries the heads per lane and the switches."""
+    from repro.traffic import AppSpec, ScenarioSpec, build_workload
+
+    d = str(tmp_path / "trace")
+    topo = HyperX(n=4, q=3)
+    engine = SimEngine(topo, mode="omniwar")
+    try:
+        obs_trace.configure(d)
+        wl = build_workload(topo, ScenarioSpec(apps=(
+            AppSpec(phases="all_to_all", placement="diagonal", ranks=16),
+            AppSpec(phases="all_to_all", placement="diagonal", ranks=8))))
+        engine.run(wl, seed=0, horizon=2)
+    finally:
+        obs_trace.disable()
+    with open(os.path.join(d, "events.jsonl")) as f:
+        events = [json.loads(line) for line in f]
+    place = [e for e in events if e.get("name") == "alloc.place"]
+    assert [(e["strategy"], e["q"], e["ranks"], e["switches"])
+            for e in place] == [("diagonal", 3, 16, 4),
+                                ("diagonal", 3, 8, 2)]
+    assert all(e["type"] == "span" and e["dur_s"] >= 0 for e in place)
+    dispatch, = [e for e in events if e.get("name") == "engine.dispatch"]
+    assert dispatch["heads"] == engine.heads_per_lane == 64 * 16 * 7
+    assert dispatch["switches"] == 64
+
+
 STAGES = ("heads", "route", "arbitrate", "dequeue", "deliver", "move",
           "complete", "inject")
 
@@ -254,6 +283,8 @@ def test_engine_stages_on_profiler_clock(tmp_path):
         assert dispatch["new_key"] == (1 if i == 0 else 0)
         assert dispatch["api"] == "run_grid"
         assert dispatch["lanes"] == dispatch["padded_lanes"] == 4
+        assert dispatch["heads"] == engine.heads_per_lane
+        assert dispatch["switches"] == SMALL.num_switches
         assert inside[3][3]["arrays"] == 4 * 11
 
 

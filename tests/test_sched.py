@@ -343,6 +343,11 @@ def test_ledger_topo_mismatch_rejected():
                      allocator=BlockLedger(PAPER))
 
 
+def test_ledger_refuses_q3_until_its_slot_views_are_generalised():
+    with pytest.raises(ValueError, match="q=3"):
+        BlockLedger(HyperX(n=4, q=3))
+
+
 # ------------------------------------------------------- interference bridge
 def _small_stream_snapshots(strategies, num_jobs=200):
     jobs = poisson_stream(
