@@ -135,9 +135,9 @@ class LinkViews:
         self.h_pool = st.h_pool.astype(I32)
 
     def per_switch(self, a):
-        """``(S, q*n) -> (H, q*n)``: every head sees its switch's row."""
-        S, HS, QN = self.S, self.HS, self.QN
-        return jnp.broadcast_to(a[:, None, :], (S, HS, QN)).reshape(S * HS, QN)
+        """``(S, W) -> (H, W)``: every head sees its switch's row."""
+        S, HS, W = self.S, self.HS, a.shape[1]
+        return jnp.broadcast_to(a[:, None, :], (S, HS, W)).reshape(S * HS, W)
 
     def per_link(self, a):
         """A per-(switch, in-port) vector ``a`` (``(S*IN,)``) behind every
@@ -165,6 +165,24 @@ class LinkViews:
         for v in range(1, V):
             out = jnp.where(vc == v, rows[..., v], out)
         return out.reshape(S * self.HS, QN)
+
+
+def pick(x, idx):
+    """``x[h, idx[h]]`` for every row of an ``(H, W)`` array: a one-hot
+    reduce over the row's ``W`` lanes, where a per-row gather would read
+    one element a head (an ``idx`` outside ``[0, W)`` reads 0 or False)."""
+    hot = jnp.arange(x.shape[1], dtype=I32)[None, :] == idx[:, None]
+    if x.dtype == jnp.bool_:
+        return (hot & x).any(axis=1)
+    return jnp.where(hot, x, jnp.zeros((), x.dtype)).sum(axis=1)
+
+
+def first_min(x):
+    """Row minimum of an ``(H, W)`` array and the first lane holding it
+    (``jnp.argmin``'s tie rule)."""
+    lo = x.min(axis=1)
+    lane = jnp.arange(x.shape[1], dtype=I32)[None, :]
+    return lo, jnp.where(x == lo[:, None], lane, x.shape[1]).min(axis=1)
 
 
 def build_step(
@@ -342,14 +360,17 @@ def build_step(
                     legal = (is_min_h | escalate) & room & avail_net
                 cost = occ * 8 + PEN * (~is_min) + jitter
                 cost = jnp.where(legal, cost, BIGCOST)
-                best = jnp.argmin(cost, axis=1).astype(I32)     # (H,)
-                best_cost = jnp.take_along_axis(cost, best[:, None], 1)[:, 0]
-                has_port = best_cost < BIGCOST
-                best_min = jnp.take_along_axis(is_min, best[:, None], 1)[:, 0]
+                cost_lo, best = first_min(cost)                 # (H,)
+                has_port = cost_lo < BIGCOST
+                best_min = pick(is_min, best)
 
+                # a legal network port has output tokens (avail_net), so
+                # only an ejection reads its output's tokens here
                 out_port = jnp.where(at_dst, q * n + dof, best)
-                requesting = exists & (at_dst | has_port)
-                requesting = requesting & (busy_dec[cur * OUT + out_port] < 2)
+                busy_ej = links.per_switch(
+                    busy_dec.reshape(S, OUT)[:, q * n:])        # (H, conc)
+                requesting = exists & jnp.where(
+                    at_dst, pick(busy_ej, dof) < 2, has_port)
                 # NOTE: scatter/gather OOB markers must be POSITIVE out-of-range —
                 # negative indices wrap NumPy-style in jnp .at[] even with
                 # mode='drop'.
@@ -369,7 +390,7 @@ def build_step(
                 won1, g1 = arbitrate(req_out, packed)
 
                 qi_down = links.down_index(vcn)                 # (H, q*n)
-                qi_best1 = jnp.take_along_axis(qi_down, best[:, None], 1)[:, 0]
+                qi_best1 = pick(qi_down, best)
                 arr1 = jnp.zeros(NQ, dtype=I32).at[
                     jnp.where(won1 & ~at_dst, qi_best1, NQ + 1)
                 ].add(1, mode="drop")
@@ -378,12 +399,14 @@ def build_step(
                 loser = requesting & ~won1
                 # re-route: best legal port with tokens left and downstream room
                 # (accounting for the round-1 arrival into the same queue)
-                tok_net = links.per_switch(tokens.reshape(S, OUT)[:, :q * n]) > 0
+                tok_sw = tokens.reshape(S, OUT)
+                tok_net = links.per_switch(tok_sw[:, :q * n]) > 0
                 room_2 = links.down_view(qlen + arr1, vcn) < CAP
                 cost2 = jnp.where(legal & tok_net & room_2, cost, BIGCOST)
-                best2 = jnp.argmin(cost2, axis=1).astype(I32)
-                has2 = jnp.take_along_axis(cost2, best2[:, None], 1)[:, 0] < BIGCOST
-                ej_ok = at_dst & (tokens[cur * OUT + q * n + dof] > 0)
+                cost2_lo, best2 = first_min(cost2)
+                has2 = cost2_lo < BIGCOST
+                tok_ej = links.per_switch(tok_sw[:, q * n:])    # (H, conc)
+                ej_ok = at_dst & (pick(tok_ej, dof) > 0)
                 out2 = jnp.where(at_dst, q * n + dof, best2)
                 req2 = loser & jnp.where(at_dst, ej_ok, has2)
                 req_out2 = jnp.where(req2, cur * OUT + out2, OOB_OUT)
@@ -391,24 +414,13 @@ def build_step(
                 won = won1 | won2
 
                 # final chosen queue / minimality per winner
-                qi_best = jnp.where(
-                    won2,
-                    jnp.take_along_axis(
-                        qi_down, jnp.minimum(best2, q * n - 1)[:, None], 1
-                    )[:, 0],
-                    qi_best1,
-                )
-                bmin = jnp.where(
-                    won2,
-                    jnp.take_along_axis(
-                        is_min, jnp.minimum(best2, q * n - 1)[:, None], 1
-                    )[:, 0],
-                    best_min,
-                )
+                best2c = jnp.minimum(best2, q * n - 1)
+                qi_best = jnp.where(won2, pick(qi_down, best2c), qi_best1)
+                bmin = jnp.where(won2, pick(is_min, best2c), best_min)
                 # per-winner escalation flag + round-1 arrival count into the
                 # winner's queue (the only arr1 value downstream code needs)
                 chosen = jnp.minimum(jnp.where(won2, best2, best), q * n - 1)
-                esc_chosen = jnp.take_along_axis(escalate, chosen[:, None], 1)[:, 0]
+                esc_chosen = pick(escalate, chosen)
                 arr1_tgt = arr1[qi_best]
             return won, won2, qi_best, bmin, esc_chosen, arr1_tgt, g1, g2
 
