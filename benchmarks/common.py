@@ -3,7 +3,7 @@
 The simulation-backed benchmarks build their scenario grids as *workloads
 first*, then execute them through :func:`sweep`, which groups same-config
 scenarios and dispatches each group as **one** vmapped device call via
-``SimEngine.run_batch`` — a strategy grid that used to be a serial Python
+``SimEngine.run_grid`` — a strategy grid that used to be a serial Python
 loop of per-scenario compiles is now one compile + one call per shape
 bucket.
 

@@ -6,7 +6,7 @@
                                              [--threshold 0.10]
                                              [--grids a,b,...]
                                              [--kernel lax|pallas]
-                                             [--chunk K] [--canon]
+                                             [--chunk K]
 
 Runs the canonical grids (strategy / pattern / fault sweeps on the paper
 machine) through a **fresh** ``SimEngine`` each — so compile time is
@@ -40,9 +40,8 @@ history) is missing or corrupt (validated *before* any measurement).
 
 Engine knobs under measurement: ``--arb`` / ``--kernel`` (Pallas
 arbitration / fused route+arbitrate megakernel), ``--chunk K``
-(early-exit granularity of the cycle loop), ``--canon`` (pow2 batch-axis
-canonicalization; its compile-key hit rate lands in the snapshot).  The
-persistent XLA compile cache is always on: ``JAX_COMPILATION_CACHE_DIR``
+(early-exit granularity of the cycle loop); the engine's compile-key
+hit rate lands in the snapshot.  The persistent XLA compile cache is always on: ``JAX_COMPILATION_CACHE_DIR``
 when set, else ``<repo>/.jax_cache`` (see ``repro.core.engine.cache``).
 Device time by cycle stage and host time by engine stage are the chip
 benchmark's (``python3 bench/run.py ... --trace 1``), not this file's.
@@ -120,7 +119,7 @@ GRIDS = {
 # ----------------------------------------------------------------- measuring
 def measure_grid(workloads, seeds, mode, horizon,
                  topo=PAPER_TOPO, arb: str = "lax", kernel: str = "lax",
-                 chunk: int = 1, canon: bool = False) -> dict:
+                 chunk: int = 1) -> dict:
     """Time one grid through a fresh engine: compile vs steady-state.
 
     The engine is constructed directly (bypassing the ``get_engine``
@@ -136,7 +135,7 @@ def measure_grid(workloads, seeds, mode, horizon,
     if len(num_pools) != 1:
         raise ValueError(f"grid mixes VC pool counts {sorted(num_pools)}")
     engine = SimEngine(topo, mode=mode, num_pools=num_pools.pop(), arb=arb,
-                       kernel=kernel, chunk=chunk, canon=canon)
+                       kernel=kernel, chunk=chunk)
     preps = [engine.prepare(w) for w in workloads]
     buckets = {p.tables.shape_bucket for p in preps}
 
@@ -186,10 +185,10 @@ def current_rev() -> str:
 
 
 def run_suite(quick: bool = True, grids=None, arb: str = "lax",
-              kernel: str = "lax", chunk: int = 1, canon: bool = False) -> dict:
+              kernel: str = "lax", chunk: int = 1) -> dict:
     """Measure every requested grid; returns the BENCH json payload."""
     names = list(GRIDS) if not grids else [g for g in GRIDS if g in grids]
-    knobs = {"arb": arb, "kernel": kernel, "chunk": chunk, "canon": canon}
+    knobs = {"arb": arb, "kernel": kernel, "chunk": chunk}
     bench = {
         "schema": SCHEMA,
         "rev": current_rev(),
@@ -208,8 +207,7 @@ def run_suite(quick: bool = True, grids=None, arb: str = "lax",
         print(f"# measuring {name} ({len(wls)} workloads x "
               f"{len(seeds)} seeds)...", file=sys.stderr)
         bench["grids"][name] = measure_grid(
-            wls, seeds, mode, horizon, arb=arb, kernel=kernel, chunk=chunk,
-            canon=canon)
+            wls, seeds, mode, horizon, arb=arb, kernel=kernel, chunk=chunk)
     return bench
 
 
@@ -225,7 +223,7 @@ def append_history(bench: dict, path: str | None = None) -> dict:
     entry = {
         k: bench[k]
         for k in ("schema", "rev", "quick", "backend", "devices", "jax",
-                  "arb", "kernel", "chunk", "canon")
+                  "arb", "kernel", "chunk")
         if k in bench
     }
     entry["date"] = time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())
@@ -324,9 +322,6 @@ def main(argv=None) -> int:
     p.add_argument("--chunk", type=int, default=1, metavar="K",
                    help="cycle-loop early-exit granularity (all_done "
                         "checked every K cycles; K=1 = reference)")
-    p.add_argument("--canon", action="store_true",
-                   help="pow2-canonicalize batch-axis lengths (compile "
-                        "sharing across nearby grid sizes)")
     p.add_argument("--history", default=None, metavar="PATH",
                    help="history jsonl to append/compare "
                         "(default <repo>/BENCH_history.jsonl)")
@@ -375,7 +370,7 @@ def main(argv=None) -> int:
             return EXIT_BAD_BASELINE
 
     bench = run_suite(quick=not args.full, grids=grids, arb=args.arb,
-                      kernel=args.kernel, chunk=args.chunk, canon=args.canon)
+                      kernel=args.kernel, chunk=args.chunk)
     out = args.out or os.path.join(REPO_ROOT, f"BENCH_{bench['rev']}.json")
     os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
     with open(out, "w") as f:

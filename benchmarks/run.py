@@ -9,7 +9,7 @@
 --quick trims replica counts / kernel sets (1-core CPU friendly); --full
 runs the complete paper grids.  Default: quick.
 --seeds N fans every simulated scenario across N seeds — the seed axis is
-batched through ``SimEngine.run_batch`` (same device call as the strategy
+batched through ``SimEngine.run_grid`` (same device call as the strategy
 axis), and rows report means over seeds.
 --csv DIR additionally writes every emitted table to DIR/<name>.csv so
 perf trajectories land in versionable files.
@@ -64,7 +64,7 @@ def main(argv=None):
     p.add_argument("--full", action="store_true")
     p.add_argument("--only", default=None)
     p.add_argument("--seeds", type=int, default=1,
-                   help="seeds per scenario, fanned through run_batch")
+                   help="seeds per scenario, fanned through run_grid")
     p.add_argument("--csv", default=None, metavar="DIR",
                    help="also write each table to DIR/<name>.csv")
     p.add_argument("--routing", default="omniwar",

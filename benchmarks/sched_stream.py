@@ -15,7 +15,7 @@ stream, so per-strategy deltas are placement effects, not arrival noise):
 
 Interference: co-resident snapshots from the poisson run are lowered to
 machine workloads and the whole strategy x snapshot x seed grid executes
-through ``SimEngine.run_batch_seeds`` — one compile + one device call per
+through ``SimEngine.run_grid`` — one compile + one device call per
 shape bucket (the compile-stats table reports the counters).
 """
 

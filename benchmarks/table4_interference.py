@@ -3,7 +3,7 @@ background), slowdown relative to Diagonal.
 
 Per kernel, the full strategy grid (isolated + with-background workloads)
 goes through one ``sweep`` call: the background grid shares one shape
-bucket, so it executes as a single vmapped ``run_batch`` device call."""
+bucket, so it executes as a single vmapped ``run_grid`` device call."""
 
 from benchmarks.common import (
     STRATEGIES,

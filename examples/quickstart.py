@@ -6,7 +6,7 @@
 from repro.core.hyperx import HyperX
 from repro.core.allocation import allocate_partition, machine_partitions
 from repro.core.properties import analyze_partition
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.engine import SimEngine
 from repro.fabric.placement import place_job
 from repro.fabric.collective_model import CollectiveModel
@@ -39,8 +39,8 @@ def main():
         workloads.append(
             tr.compose_workload(topo, [(tr.all_to_all(64), p) for p in parts])
         )
-    results = engine.run_batch(workloads, horizon=40000)
-    for strat, res in zip(strategies, results):
+    results = engine.run_grid(workloads, horizon=40000)
+    for strat, (res,) in zip(strategies, results):
         print(f"{strat:10s} 8x all-to-all makespan = "
               f"{res.makespan_cycles} cycles (avg hops {res.avg_hops:.2f})")
 
@@ -86,8 +86,8 @@ def main():
         )
         for strat in ("diagonal", "rectangular")
     ]
-    for strat, res in zip(("diagonal", "rectangular"),
-                          ugal.run_batch(faulty, horizon=40000)):
+    for strat, (res,) in zip(("diagonal", "rectangular"),
+                             ugal.run_grid(faulty, horizon=40000)):
         print(f"{strat:12s} makespan = {res.makespan_cycles} cycles "
               f"(avg hops {res.avg_hops:.2f}, max hops {res.max_hops} "
               f"< VC budget {ugal.static.V})")
@@ -108,8 +108,8 @@ def main():
         )))
         for strat in ("diagonal", "rectangular")
     ]
-    for strat, res in zip(("diagonal", "rectangular"),
-                          engine.run_batch(phased, horizon=40000)):
+    for strat, (res,) in zip(("diagonal", "rectangular"),
+                             engine.run_grid(phased, horizon=40000)):
         print(f"{strat:12s} stencil+all_reduce makespan = "
               f"{res.makespan_cycles} cycles (avg hops {res.avg_hops:.2f})")
 

@@ -3,8 +3,8 @@
 Public surface:
 
   * :class:`SimEngine` / :func:`get_engine` — compile-once, run-many
-    execution with ``run`` / ``run_batch`` / ``run_seeds`` and the
-    device-sharded ``run_grid`` (lane axis over shard_map / vmap);
+    execution: ``run_grid`` runs a workload x seed cross product (nested
+    vmap on one device, lanes over shard_map on more), ``run`` one lane;
   * :class:`WorkloadTables` / :func:`make_workload_tables` — per-workload
     device data as a padded pytree of jit arguments (packed to
     int8/int16 by bucket-derived bounds; see :mod:`.packing`);
@@ -12,9 +12,6 @@ Public surface:
   * :mod:`.arb` — switch-arbitration backends (lax scatter-min
     reference and the bit-exact per-switch Pallas kernel);
   * :class:`SimState`, :class:`SimResult` — simulation state & summary.
-
-The legacy entry points ``build_simulator`` / ``simulate`` in
-:mod:`repro.core.simulator` are thin facades over this package.
 """
 
 from repro.core.engine.arb import arbitrate_lax, make_arbiter

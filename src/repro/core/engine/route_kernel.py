@@ -47,6 +47,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.engine.arb import INVALID_KEY, order_keys
+from repro.core.engine.tables import onward_index
 from repro.route import get_policy
 
 I32 = jnp.int32
@@ -87,12 +88,19 @@ def make_fused_router(st, interpret: bool | None = None) -> Callable:
     pdim_t = jnp.asarray(pdim_np.reshape(1, QN))
     pval_t = jnp.asarray(st.port_val, dtype=I32).reshape(1, QN)
     hpool_t = st.h_pool.astype(I32).reshape(S, HS, 1)
+    # minimal-only escapes read the links onward from each port's
+    # neighbour: one (n, QN) slab per switch (tables.onward_index)
+    onward_t = None if adaptive else jnp.asarray(
+        onward_index(st).transpose(1, 0, 2))         # (S, n, QN)
 
     def kernel(exists_ref, at_dst_ref, dof_ref, der_ref, vcn_ref, pool_ref,
                key_ref, cdst_ref, jit_ref, curd_ref, down_ref, lok_ref,
                occ_ref, busy_ref, slab_ref, pdim_ref, pval_ref, ndead_ref,
-               won_ref, won2_ref, qib_ref, bmin_ref, esc_ref, a1t_ref,
-               g1_ref, g2_ref):
+               *refs):
+        # the onward-link slab is an input of minimal-only policies only
+        onward_ref = None if adaptive else refs[0]
+        (won_ref, won2_ref, qib_ref, bmin_ref, esc_ref, a1t_ref,
+         g1_ref, g2_ref) = refs[-8:]
         # heads run down the sublanes, ports along the lanes: per-head
         # values are (HS, 1) columns, per-port values (1, QN) rows, and
         # every gather is a one-hot select over a lane or sublane axis
@@ -164,10 +172,17 @@ def make_fused_router(st, interpret: bool | None = None) -> Callable:
             )
         else:
             is_min_h = is_min & healthy
-            escalate = (
+            escape = (
                 ~is_min_h.any(axis=1, keepdims=True)
                 & unaligned & not_self & healthy & (der > 0)
             )
+            onward_rows = onward_ref[0] != 0         # (n, QN)
+            onward = escape & (dst_d == 0) & onward_rows[0:1, :]
+            for w in range(1, st.n):
+                onward = onward | (escape & (dst_d == w)
+                                   & onward_rows[w:w + 1, :])
+            has_onward = onward.any(axis=1, keepdims=True)
+            escalate = (has_onward & onward) | (~has_onward & escape)
             legal = (is_min_h | escalate) & room & avail_net
         cost = occ_row * 8 + jnp.where(is_min, 0, PEN) + jitter
         cost = jnp.where(legal, cost, BIGCOST)
@@ -245,7 +260,7 @@ def make_fused_router(st, interpret: bool | None = None) -> Callable:
             row, row, row, row,                        # cur_d/down/lok/occ
             per_switch(1, OUT), per_switch(PV, QN),    # busy, qlen slab
             whole(1, QN), whole(1, QN), whole(1, 1),   # pdim/pval/ndead
-        ],
+        ] + ([] if adaptive else [per_switch(st.n, QN)]),  # onward links
         out_specs=[head] * 6 + [per_switch(1, OUT)] * 2,
         out_shape=[jax.ShapeDtypeStruct((S, HS, 1), I32)] * 6
         + [jax.ShapeDtypeStruct((S, 1, OUT), I32)] * 2,
@@ -264,13 +279,15 @@ def make_fused_router(st, interpret: bool | None = None) -> Callable:
         cdst = st.coords[route_dsw].astype(I32).reshape(S, HS, q)
         occ = port_occ[down_t].reshape(S, 1, QN)
         slab = qlen.reshape(S * IN, PV)[down_t].transpose(0, 2, 1)
+        lok = link_ok_t.astype(I32)
+        onward = [] if adaptive else [lok.reshape(-1)[onward_t]]
         outs = call(
             col(exists), col(at_dst), col(dof), col(der), col(vcn), hpool_t,
             order_keys(packed).reshape(S, HS, 1), cdst,
             jitter.reshape(S, HS, QN), cur_d_t, down_t.reshape(S, 1, QN),
-            link_ok_t.astype(I32).reshape(S, 1, QN), occ,
+            lok.reshape(S, 1, QN), occ,
             busy_dec.reshape(S, 1, OUT), slab, pdim_t, pval_t,
-            jnp.reshape(n_dead_t, (1, 1)).astype(I32),
+            jnp.reshape(n_dead_t, (1, 1)).astype(I32), *onward,
         )
         won, won2, qib, bmin, esc, a1t, g1, g2 = outs
         return (

@@ -7,15 +7,15 @@ The engine splits a simulation into
   * per-workload device data (:mod:`workload_tables`) — passed as pytree
     arguments, so the jit cache keys only on shape buckets.
 
-``run`` executes one scenario; ``run_batch`` stacks same-bucket tables and
-``jax.vmap``-s the entire ``lax.while_loop``, so a whole strategy x seed
-sweep is **one compilation and one device call** (per shape bucket).
-``run_seeds`` fans one scenario across many seeds without replicating its
-tables.  ``run_grid`` flattens a workload x seed cross product into a
-*lane* axis and shards it across every local device (``jax.shard_map``
-over a 1-D mesh; the nested-vmap path on a single device) — lanes are
-embarrassingly parallel, so an N-device host runs an N-times-wider grid
-at the same wall-clock per bucket.
+``run_grid`` runs a workload x seed cross product: same-bucket tables
+are stacked and the entire ``lax.while_loop`` is vmapped, so a whole
+strategy x seed sweep is **one compilation and one device call** (per
+shape bucket).  On one device the grid is a nested vmap (seeds
+broadcast, tables never replicated); on more, it is flattened into a
+*lane* axis sharded across every local device (``jax.shard_map`` over a
+1-D mesh) — lanes are embarrassingly parallel, so an N-device host runs
+an N-times-wider grid at the same wall-clock per bucket.  ``run`` is the
+1 x 1 grid.
 
 Engines are memoised by :func:`get_engine`; ``trace_count`` /
 ``device_calls`` expose how many XLA traces and dispatches actually
@@ -42,11 +42,11 @@ from repro.core.engine.workload_tables import (
     stack_tables,
 )
 from repro.core.hyperx import HyperX
-from repro.core.traffic import Workload
 from repro.obs import probes as obs_probes
 from repro.obs import trace as obs_trace
 from repro.obs.probes import Telemetry, TelemetrySpec, init_telemetry
 from repro.route import get_policy
+from repro.traffic.workload import Workload
 
 PACKET_FLITS = 16  # paper Table 2: packet size 16 flits
 
@@ -124,7 +124,6 @@ class SimEngine:
         telemetry: TelemetrySpec | None = None,
         kernel: str = "lax",
         chunk: int = 1,
-        canon: bool = False,
     ):
         if chunk < 1:
             raise ValueError(f"chunk must be >= 1, got {chunk}")
@@ -137,7 +136,6 @@ class SimEngine:
         self.telemetry = telemetry
         self.kernel = kernel
         self.chunk = chunk
-        self.canon = canon
         self.static = build_static_tables(
             topo, mode=mode, num_pools=num_pools, max_deroutes=max_deroutes,
             cap=cap, penalty_packets=penalty_packets, arb=arb,
@@ -221,9 +219,6 @@ class SimEngine:
                 )
 
         self._core = core
-        self._run1 = jax.jit(core)
-        self._runN = jax.jit(jax.vmap(core, in_axes=(0, 0, None)))
-        self._runS = jax.jit(jax.vmap(core, in_axes=(None, 0, None)))
         # (workloads x seeds) cross product: tables batch on the outer axis
         # only, seeds broadcast on the inner — no per-seed table replication
         self._runNS = jax.jit(jax.vmap(
@@ -255,27 +250,6 @@ class SimEngine:
                 f"built with num_pools={self.num_pools}"
             )
         return prep
-
-    # --------------------------------------------- shape canonicalization
-    def _canon_pad(self, count: int) -> int:
-        """Canonical batch-axis length: next power of two (``canon`` only).
-
-        Workload tables already pow2-pad their own dims (R/T/D/NE — see
-        :func:`~repro.core.engine.workload_tables.shape_bucket`); the one
-        remaining compile-key degree of freedom is how many lanes are
-        stacked per dispatch.  Padding that count to a power of two makes
-        nearby grid sizes (5 vs 7 workloads, 3 vs 4 seeds) share one
-        compiled executable; padded lanes repeat existing ones and their
-        results are discarded.
-        """
-        if not self.canon or count <= 1:
-            return count
-        return 1 << (count - 1).bit_length()
-
-    def _pad_idxs(self, idxs: list) -> list:
-        """Round-robin-extend ``idxs`` to its canonical length."""
-        tgt = self._canon_pad(len(idxs))
-        return idxs + [idxs[k % len(idxs)] for k in range(tgt - len(idxs))]
 
     def _note_bucket(self, fn: str, bucket, dims: tuple) -> bool:
         """Account one dispatch against the compile-key it lands on;
@@ -317,76 +291,37 @@ class SimEngine:
         seed: int = 0,
         horizon: int = 60_000,
     ) -> SimResult:
-        with obs_trace.stage("engine.prepare", api="run", workloads=1):
-            prep = self.prepare(wl)
-        out = self._dispatch(
-            "run", self._run1, "run1", prep.tables.shape_bucket, (), 1,
-            prep.tables, jnp.int32(seed), horizon,
-        )
-        with self._results_span(out, 1):
-            return self._to_result(out, prep)
+        """One workload, one seed: the grid's nested-vmap cross product at
+        1 x 1 on any device count (one lane has nothing to shard)."""
+        return self._run_lanes("run", [wl], [int(seed)], horizon,
+                               sharded=False)[0][0]
 
-    def run_batch(
+    def run_grid(
         self,
         workloads: Sequence[Workload | PreparedWorkload],
         seeds: Sequence[int] | None = None,
         horizon: int = 60_000,
-    ) -> list[SimResult]:
-        """Run many scenarios as (one device call per shape bucket).
-
-        ``seeds`` has one entry per workload (default: all 0).  Workloads
-        are grouped by shape bucket internally; results come back in input
-        order.  The jit cache keys on the stacked shapes — which include
-        the batch dimension — so repeated sweeps of the same grid size
-        (e.g. one batch per kernel over a fixed strategy set) share one
-        compilation.
-        """
-        preps = self._prepare_all("run_batch", workloads)
-        if seeds is None:
-            seeds = [0] * len(preps)
-        if len(seeds) != len(preps):
-            raise ValueError(
-                f"{len(seeds)} seeds for {len(preps)} workloads"
-            )
-        groups: dict[tuple[int, int, int, int], list[int]] = {}
-        for i, p in enumerate(preps):
-            groups.setdefault(p.tables.shape_bucket, []).append(i)
-        results: list[SimResult | None] = [None] * len(preps)
-        for idxs in groups.values():
-            # canon: pad the stacked axis to a power of two (padded lanes
-            # repeat real ones; their rows are simply never read back)
-            idxs_p = self._pad_idxs(idxs)
-            stacked, seed_arr = self._stack(
-                preps, idxs_p, [int(seeds[i]) for i in idxs_p]
-            )
-            outs = self._dispatch(
-                "run_batch", self._runN, "runN",
-                preps[idxs[0]].tables.shape_bucket, (len(idxs_p),),
-                len(idxs), stacked, seed_arr, horizon,
-            )
-            with self._results_span(outs, len(idxs)):
-                for j, i in enumerate(idxs):
-                    results[i] = self._to_result(_index_outs(outs, j),
-                                                 preps[i])
-        return results  # type: ignore[return-value]
-
-    def run_batch_seeds(
-        self,
-        workloads: Sequence[Workload | PreparedWorkload],
-        seeds: Sequence[int],
-        horizon: int = 60_000,
     ) -> list[list[SimResult]]:
-        """Cross product: every workload x every seed, one device call per
-        shape bucket.  Tables batch only on the workload axis (seeds
-        broadcast), so nothing is replicated per seed.  Returns
-        ``results[workload][seed]`` in input order.
-        """
-        preps = self._prepare_all("run_batch_seeds", workloads)
-        return self._run_cross(
-            "run_batch_seeds", preps, [int(s) for s in seeds], horizon
-        )
+        """Run the workload x seed cross product, one device call per
+        shape bucket; results come back as ``results[workload][seed]`` in
+        input order (``seeds`` defaults to ``[0]``).
 
-    # ------------------------------------------------- device-sharded lanes
+        On one device the grid runs as a nested vmap: tables batch on the
+        workload axis, seeds broadcast on the inner one, so nothing is
+        replicated per seed.  On more, it is flattened into a *lane* axis
+        (one lane per (workload, seed) pair) and sharded with
+        ``jax.shard_map`` over a 1-D device mesh; lanes are padded
+        round-robin to a multiple of the device count, so every device
+        receives equal work, and padded lanes are computed and discarded.
+        Results are bitwise identical on every backend (lane flattening
+        only re-associates the batch axes); ``self.lane_backend`` records
+        which layout runs.  A zip of per-workload seeds is the diagonal,
+        ``[r[i] for i, r in enumerate(grid)]``.
+        """
+        seeds = [0] if seeds is None else [int(s) for s in seeds]
+        return self._run_lanes("run_grid", workloads, seeds, horizon,
+                               sharded=jax.local_device_count() > 1)
+
     def _make_lane_runner(self):
         """Build the multi-device lane dispatcher (``jax.shard_map``).
 
@@ -405,157 +340,52 @@ class SimEngine:
             check_vma=False,
         ))
 
-    def run_grid(
-        self,
-        workloads: Sequence[Workload | PreparedWorkload],
-        seeds: Sequence[int] | None = None,
-        horizon: int = 60_000,
-    ) -> list[list[SimResult]]:
-        """Run the workload x seed cross product sharded across devices.
-
-        The grid is flattened into a *lane* axis (one lane per
-        (workload, seed) pair, grouped by shape bucket) and dispatched
-
-          * via ``jax.shard_map`` over a 1-D device mesh when the host has
-            more than one device — lanes are padded round-robin to a
-            multiple of the device count so uneven grids still compile
-            once per (bucket, lane-count) and every device receives equal
-            work;
-          * via the existing nested-vmap path (``run_batch_seeds``'s
-            dispatch — seeds broadcast, tables never replicated) on a
-            single device.
-
-        Results are bitwise identical to ``run_batch_seeds`` on every
-        backend (lane flattening only re-associates the batch axes) and
-        come back as ``results[workload][seed]`` in input order.
-        ``self.lane_backend`` records which dispatcher ran.
-        """
-        preps = self._prepare_all("run_grid", workloads)
-        seeds = [0] if seeds is None else [int(s) for s in seeds]
-        ndev = jax.local_device_count()
-        if ndev == 1:
-            # single device: the nested-vmap cross product is already the
-            # fastest layout (no table replication across the seed axis)
-            return self._run_cross("run_grid", preps, seeds, horizon)
-
-        groups: dict[tuple[int, int, int, int], list[int]] = {}
-        for i, p in enumerate(preps):
-            groups.setdefault(p.tables.shape_bucket, []).append(i)
-        results: list[list[SimResult] | None] = [None] * len(preps)
-        if self._lane_runner is None:
-            self._lane_runner = self._make_lane_runner()
-        for idxs in groups.values():
-            lanes = [(i, k) for i in idxs for k in range(len(seeds))]
-            # canon first (pow2 lane count), then to a device-count
-            # multiple so every shard is full
-            tgt = self._canon_pad(len(lanes))
-            tgt += (-tgt) % ndev
-            pad = tgt - len(lanes)
-            # round-robin padding: repeat existing lanes so every device
-            # shard is full; padded lanes are computed and discarded
-            lanes_p = lanes + [lanes[k % len(lanes)] for k in range(pad)]
-            stacked, seed_arr = self._stack(
-                preps, [i for i, _ in lanes_p], [seeds[k] for _, k in lanes_p]
-            )
-            outs = self._dispatch(
-                "run_grid", self._lane_runner, "lanes",
-                preps[idxs[0]].tables.shape_bucket, (len(lanes_p),),
-                len(lanes), stacked, seed_arr, horizon,
-            )
-            with self._results_span(outs, len(lanes)):
-                for lane, (i, k) in enumerate(lanes):
-                    if results[i] is None:
-                        results[i] = [None] * len(seeds)  # type: ignore
-                    results[i][k] = self._to_result(
-                        _index_outs(outs, lane), preps[i]
-                    )
-        return results  # type: ignore[return-value]
-
-    def run_seeds(
-        self,
-        wl: Workload | PreparedWorkload,
-        seeds: Sequence[int],
-        horizon: int = 60_000,
-    ) -> list[SimResult]:
-        """One scenario, many seeds — tables are not replicated on device."""
-        with obs_trace.stage("engine.prepare", api="run_seeds", workloads=1):
-            prep = self.prepare(wl)
-        seeds_p = self._pad_idxs([int(s) for s in seeds])
-        seed_arr = jnp.asarray(seeds_p, dtype=jnp.int32)
-        outs = self._dispatch(
-            "run_seeds", self._runS, "runS", prep.tables.shape_bucket,
-            (len(seeds_p),), len(seeds), prep.tables, seed_arr, horizon,
-        )
-        with self._results_span(outs, len(seeds)):
-            return [
-                self._to_result(_index_outs(outs, j), prep)
-                for j in range(len(seeds))
-            ]
-
-    def run_debug(
-        self,
-        wl: Workload | PreparedWorkload,
-        seed: int = 0,
-        steps: int = 512,
-        stride: int = 16,
-    ):
-        """Scan ``steps`` cycles; return per-stride (delivered, injected, qsum)."""
-        prep = self.prepare(wl)
-        wt = prep.tables
-
-        def body(state, _):
-            s2 = self._step(state, wt)
-            return s2, (s2.n_delivered, s2.n_injected, s2.qlen.sum())
-
-        state = init_state(self.static, wt, seed)
-        final, (d, i, qs) = jax.lax.scan(body, state, None, length=steps)
-        return (
-            final,
-            np.asarray(d)[::stride],
-            np.asarray(i)[::stride],
-            np.asarray(qs)[::stride],
-        )
-
     # ------------------------------------------------------------ private
     # Host stages of a call, each an ``obs.trace.stage`` span (on the
     # profiler's clock, and in the JSONL log while a tracer is active):
     # engine.prepare -> engine.stack -> engine.dispatch -> engine.to_result.
-    def _prepare_all(self, api: str, workloads) -> list[PreparedWorkload]:
+    def _run_lanes(self, api, workloads, seeds, horizon, sharded):
+        """The workload x seed cross product, one dispatch per shape
+        bucket, on the nested vmap or (``sharded``) the shard_map lanes."""
         with obs_trace.stage("engine.prepare", api=api,
                              workloads=len(workloads)):
-            return [self.prepare(w) for w in workloads]
+            preps = [self.prepare(w) for w in workloads]
+        groups: dict[tuple[int, int, int, int], list[int]] = {}
+        for i, p in enumerate(preps):
+            groups.setdefault(p.tables.shape_bucket, []).append(i)
+        results: list[list[SimResult]] = [[None] * len(seeds)  # type: ignore
+                                          for _ in preps]
+        for idxs in groups.values():
+            bucket = preps[idxs[0]].tables.shape_bucket
+            lanes = [(i, k) for i in idxs for k in range(len(seeds))]
+            if sharded:
+                if self._lane_runner is None:
+                    self._lane_runner = self._make_lane_runner()
+                pad = -len(lanes) % jax.local_device_count()
+                lanes_p = lanes + [lanes[k % len(lanes)] for k in range(pad)]
+                stacked, seed_arr = self._stack(
+                    preps, [i for i, _ in lanes_p],
+                    [seeds[k] for _, k in lanes_p],
+                )
+                runner, fn, dims = self._lane_runner, "lanes", (len(lanes_p),)
+            else:
+                stacked, seed_arr = self._stack(preps, idxs, seeds)
+                runner, fn, dims = self._runNS, "runNS", (len(idxs),
+                                                          len(seeds))
+            outs = self._dispatch(api, runner, fn, bucket, dims, len(lanes),
+                                  stacked, seed_arr, horizon)
+            with self._results_span(outs, len(lanes)):
+                for lane, (i, k) in enumerate(lanes):
+                    at = lane if sharded else (lane // len(seeds), k)
+                    results[i][k] = self._to_result(_index_outs(outs, at),
+                                                    preps[i])
+        return results
 
     def _stack(self, preps, idxs, seeds) -> tuple[WorkloadTables, jax.Array]:
         """The stacked tables of ``preps[idxs]`` and the seed array."""
         with obs_trace.stage("engine.stack", tables=len(idxs)):
             return (stack_tables([preps[i].tables for i in idxs]),
                     jnp.asarray(seeds, dtype=jnp.int32))
-
-    def _run_cross(self, api, preps, seeds, horizon):
-        """The workload x seed cross product through the nested vmap, one
-        dispatch per shape bucket (``run_batch_seeds``, and ``run_grid``
-        on one device)."""
-        seeds_p = self._pad_idxs(seeds)
-        groups: dict[tuple[int, int, int, int], list[int]] = {}
-        for i, p in enumerate(preps):
-            groups.setdefault(p.tables.shape_bucket, []).append(i)
-        results: list[list[SimResult] | None] = [None] * len(preps)
-        for idxs in groups.values():
-            idxs_p = self._pad_idxs(idxs)
-            stacked, seed_arr = self._stack(preps, idxs_p, seeds_p)
-            outs = self._dispatch(
-                api, self._runNS, "runNS",
-                preps[idxs[0]].tables.shape_bucket,
-                (len(idxs_p), len(seeds_p)), len(idxs) * len(seeds),
-                stacked, seed_arr, horizon,
-            )
-            with self._results_span(outs, len(idxs) * len(seeds)):
-                for j, i in enumerate(idxs):
-                    results[i] = [
-                        self._to_result(_index_outs(outs, (j, k)), preps[i])
-                        for k in range(len(seeds))
-                    ]
-        return results
 
     def _dispatch(self, api, runner, fn, bucket, dims, lanes, tables, seeds,
                   horizon):
@@ -622,12 +452,11 @@ class SimEngine:
 
 @functools.lru_cache(maxsize=None)
 def _engine_for(topo, mode, num_pools, max_deroutes, cap, penalty_packets,
-                bucket, arb, pack, telemetry, kernel, chunk, canon):
+                bucket, arb, pack, telemetry, kernel, chunk):
     return SimEngine(
         topo, mode=mode, num_pools=num_pools, max_deroutes=max_deroutes,
         cap=cap, penalty_packets=penalty_packets, bucket=bucket, arb=arb,
         pack=pack, telemetry=telemetry, kernel=kernel, chunk=chunk,
-        canon=canon,
     )
 
 
@@ -644,7 +473,6 @@ def get_engine(
     telemetry: TelemetrySpec | None = None,
     kernel: str = "lax",
     chunk: int = 1,
-    canon: bool = False,
 ) -> SimEngine:
     """Memoised engine lookup: one engine (and one compile) per config.
 
@@ -655,8 +483,7 @@ def get_engine(
     ("lax" | "pallas" fused megakernel, bit identical); ``chunk`` is the
     early-exit granularity of the cycle loop (K cycles per ``all_done``
     check — result-exact for any K, K=1 is the cycle-granular reference);
-    ``canon`` pow2-pads batch-axis lengths so nearby grid sizes share
-    compiles; ``pack`` controls int8/int16 table packing (default on —
+    ``pack`` controls int8/int16 table packing (default on —
     ``False`` is the int32 reference layout for parity tests).
     ``telemetry`` (a hashable :class:`~repro.obs.probes.TelemetrySpec`)
     is part of the key: enabling probes builds a separate engine, leaving
@@ -664,5 +491,5 @@ def get_engine(
     """
     return _engine_for(
         topo, mode, num_pools, max_deroutes, cap, penalty_packets, bucket,
-        arb, pack, telemetry, kernel, chunk, canon,
+        arb, pack, telemetry, kernel, chunk,
     )

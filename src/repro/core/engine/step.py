@@ -36,7 +36,7 @@ import numpy as np
 
 from repro.core.engine.arb import make_arbiter
 from repro.core.engine.route_kernel import make_fused_router
-from repro.core.engine.tables import HEAD_BITS, StaticTables
+from repro.core.engine.tables import HEAD_BITS, StaticTables, onward_index
 from repro.core.engine.workload_tables import WorkloadTables
 from repro.obs.probes import TelemetrySpec, TelemetryState
 from repro.route import get_policy
@@ -133,6 +133,17 @@ class LinkViews:
                          + np.asarray(st.in_port_at_nb, dtype=np.int32)
                          ).reshape(-1)                    # (S * q*n,)
         self.h_pool = st.h_pool.astype(I32)
+        self.onward_idx = onward_index(st)
+
+    def onward(self, link_ok, dst_d):
+        """Whether the neighbour behind each head's port (dimension d) has
+        a healthy link of its own toward value ``dst_d[h, p]`` in d:
+        ``(H, q*n)`` bool, one select per coordinate value."""
+        rows = link_ok.reshape(-1)[self.onward_idx]     # (n, S, q*n)
+        out = jnp.zeros(dst_d.shape, jnp.bool_)
+        for w in range(rows.shape[0]):
+            out = out | ((dst_d == w) & self.per_switch(rows[w]))
+        return out
 
     def per_switch(self, a):
         """``(S, W) -> (H, W)``: every head sees its switch's row."""
@@ -351,12 +362,19 @@ def build_step(
                 else:
                     # minimal-only (min / val / ugal): when every minimal port of
                     # this switch is dead, escalate to budget-bounded deroutes so
-                    # packets can round the fault (hops stay inside the VC budget)
+                    # packets can round the fault (hops stay inside the VC budget).
+                    # An escape goes to a neighbour whose own link onward in the
+                    # port's dimension is healthy, when there is one: else two
+                    # switches that both lost their link to the target bounce a
+                    # packet between them until its budget is spent.
                     is_min_h = is_min & healthy
-                    escalate = (
+                    escape = (
                         ~is_min_h.any(axis=1, keepdims=True)
                         & unaligned & not_self & healthy & (der[:, None] > 0)
                     )
+                    onward = escape & links.onward(link_ok_t, dst_d)
+                    escalate = jnp.where(
+                        onward.any(axis=1, keepdims=True), onward, escape)
                     legal = (is_min_h | escalate) & room & avail_net
                 cost = occ * 8 + PEN * (~is_min) + jitter
                 cost = jnp.where(legal, cost, BIGCOST)

@@ -162,3 +162,13 @@ def build_static_tables(
         inj_base=lower(inj_base_np, NQ - 1),
         ep_sw=lower(e_sw, S - 1),
     )
+
+
+def onward_index(st: StaticTables) -> np.ndarray:
+    """``(n, S, q*n)`` flat indices into a ``(S, q*n)`` link mask: entry
+    ``[w, s, p]`` is the link from the neighbour behind port ``p`` of
+    switch ``s`` toward value ``w`` in that port's dimension."""
+    nbr = np.asarray(st.nbr, dtype=np.int32)
+    pdim = np.asarray(st.port_dim, dtype=np.int32)
+    w = np.arange(st.n, dtype=np.int32)[:, None, None]
+    return nbr[None] * (st.q * st.n) + pdim[None, None, :] * st.n + w

@@ -36,9 +36,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine.packing import pack
-from repro.core.traffic import Workload
 from repro.route import faults
 from repro.route.topology import self_port_mask
+from repro.traffic.workload import Workload
 
 I32 = jnp.int32
 

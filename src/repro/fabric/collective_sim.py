@@ -121,7 +121,7 @@ def compare_strategies_simulated(
 ) -> list[dict]:
     """Measured makespan of one mesh collective per allocation strategy.
 
-    All strategies execute as one batched ``run_batch`` device call (their
+    All strategies execute as one batched ``run_grid`` device call (their
     workloads share a shape bucket).  ``mode`` selects the routing policy.
     """
     from repro.fabric.placement import place_job

@@ -27,7 +27,7 @@ from repro.core.hyperx import HyperX
 from repro.route import faults
 
 if TYPE_CHECKING:  # pragma: no cover
-    from repro.core.traffic import Workload
+    from repro.traffic.workload import Workload
 
 
 @dataclasses.dataclass(frozen=True)

@@ -30,7 +30,7 @@ from repro.core.hyperx import HyperX
 from repro.route.topology import dst_switch_table, self_port_mask
 
 if TYPE_CHECKING:  # pragma: no cover
-    from repro.core.traffic import Workload
+    from repro.traffic.workload import Workload
 
 
 def no_faults(topo: HyperX) -> np.ndarray:

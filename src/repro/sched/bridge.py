@@ -5,7 +5,7 @@ the machine at one scheduling event.  This module lowers snapshots through
 the declarative scenario layer (:mod:`repro.traffic.scenario`) to
 :class:`~repro.traffic.workload.Workload`s (each job runs its registry
 kernel on its *actually placed* partition) and executes the whole
-strategy x snapshot x seed grid through ``SimEngine.run_batch_seeds`` — the
+strategy x snapshot x seed grid through ``SimEngine.run_grid`` — the
 engine groups workloads by shape bucket internally, so the entire grid
 costs **one compilation and one device call per shape bucket** regardless
 of how many strategies, snapshots, or seeds it spans (the trace-counter
@@ -97,7 +97,7 @@ def evaluate_snapshots(
 
     ``snapshots_by_key`` maps a label (typically the strategy name) to its
     snapshots.  ALL workloads across all keys go through one engine and one
-    ``run_batch_seeds`` call, so same-shape-bucket scenarios of different
+    ``run_grid`` call, so same-shape-bucket scenarios of different
     strategies share both the compilation and the dispatch.
 
     Returns (rows, stats): one row per (key, snapshot, seed) with the

@@ -11,7 +11,7 @@ same `make_arbiter(..., interpret=None)` resolves to a compiled kernel.
 import numpy as np
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, make_arbiter
 from repro.core.hyperx import HyperX
@@ -92,8 +92,8 @@ def test_engine_pallas_arb_bit_identical():
     for wl, seed in zip(wls, (0, 3, 9)):
         assert pal_eng.run(wl, seed=seed, horizon=5000) == lax_eng.run(
             wl, seed=seed, horizon=5000)
-    assert pal_eng.run_batch_seeds(wls, seeds=(0, 7), horizon=5000) == \
-        lax_eng.run_batch_seeds(wls, seeds=(0, 7), horizon=5000)
+    assert pal_eng.run_grid(wls, seeds=(0, 7), horizon=5000) == \
+        lax_eng.run_grid(wls, seeds=(0, 7), horizon=5000)
 
     wl = _a2a_workload("row")
     lax_val = SimEngine(SMALL, mode="val", num_pools=wl.num_pools)

@@ -10,7 +10,7 @@ chunk boundary.  K=1 is the cycle-granular reference loop itself
 
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine
 from repro.core.hyperx import HyperX

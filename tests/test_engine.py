@@ -1,19 +1,22 @@
-"""SimEngine tests: batch/single equivalence, compile sharing, facade
-regression against recorded seed-simulator outputs."""
+"""SimEngine tests: grid/single equivalence, compile sharing, regression
+against recorded seed-simulator outputs."""
 
+import jax
 import numpy as np
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import (
     SimEngine,
+    build_step,
+    get_engine,
+    init_state,
     make_workload_tables,
     shape_bucket,
     stack_tables,
 )
 from repro.core.hyperx import HyperX
-from repro.core.simulator import build_simulator, simulate
 
 SMALL = HyperX(n=4, q=2)
 
@@ -24,36 +27,33 @@ def _a2a_workload(strategy: str):
 
 
 # ------------------------------------------------------------------ batching
-def test_run_batch_bitwise_matches_run():
-    """Vmapped batch execution returns exactly the per-scenario results."""
-    engine = SimEngine(SMALL, mode="omniwar")
-    wls = [_a2a_workload(s) for s in ("row", "diagonal", "full_spread")]
-    seeds = [0, 1, 2]
-    solo = [engine.run(wl, seed=s, horizon=5000)
-            for wl, s in zip(wls, seeds)]
-    batch = engine.run_batch(wls, seeds=seeds, horizon=5000)
-    assert batch == solo  # SimResult dataclass equality: every field exact
+GRIDS = {
+    # a zip of per-workload seeds: the diagonal of the cross product
+    "diagonal": (("row", "diagonal", "full_spread"), (0, 1, 2)),
+    "workloads_x_seeds": (("row", "diagonal"), (0, 7)),
+    "one_workload_many_seeds": (("row",), (0, 5, 9)),
+}
 
 
-def test_run_batch_seeds_matches_run():
-    """Workload x seed cross product (seeds broadcast, no table
-    replication) returns exactly the per-scenario results."""
+@pytest.mark.parametrize("case", sorted(GRIDS))
+def test_run_grid_equals_solo_run(case):
+    """The vmapped workload x seed cross product (seeds broadcast, no
+    table replication) returns exactly the per-scenario results."""
+    strategies, seeds = GRIDS[case]
     engine = SimEngine(SMALL, mode="omniwar")
-    wls = [_a2a_workload(s) for s in ("row", "diagonal")]
-    seeds = (0, 7)
-    grid = engine.run_batch_seeds(wls, seeds=seeds, horizon=5000)
-    assert grid == [
-        [engine.run(wl, seed=s, horizon=5000) for s in seeds] for wl in wls
-    ]
+    wls = [_a2a_workload(s) for s in strategies]
+    grid = engine.run_grid(wls, seeds=seeds, horizon=5000)
+    if case == "diagonal":
+        assert [grid[i][i] for i in range(len(wls))] == [
+            engine.run(wl, seed=s, horizon=5000) for wl, s in zip(wls, seeds)
+        ]
+    else:
+        # SimResult dataclass equality: every field exact
+        assert grid == [
+            [engine.run(wl, seed=s, horizon=5000) for s in seeds]
+            for wl in wls
+        ]
     assert engine.trace_count == 2  # one cross-product trace + one single
-
-
-def test_run_seeds_matches_run():
-    engine = SimEngine(SMALL, mode="omniwar")
-    wl = _a2a_workload("row")
-    solo = [engine.run(wl, seed=s, horizon=5000) for s in (0, 5, 9)]
-    fanned = engine.run_seeds(wl, seeds=(0, 5, 9), horizon=5000)
-    assert fanned == solo
 
 
 # ----------------------------------------------------------- compile sharing
@@ -70,18 +70,18 @@ def test_same_shape_workloads_share_one_compilation():
 
 
 def test_strategy_grid_is_single_batched_device_call():
-    """A whole strategy grid = one run_batch dispatch; a second grid of the
+    """A whole strategy grid = one run_grid dispatch; a second grid of the
     same shapes reuses the compilation (trace count stays flat)."""
     engine = SimEngine(SMALL, mode="omniwar")
     grid1 = [_a2a_workload(s) for s in ("row", "diagonal", "full_spread")]
-    engine.run_batch(grid1, horizon=5000)
+    engine.run_grid(grid1, horizon=5000)
     assert engine.device_calls == 1          # one dispatch for the grid
     traces_after_first = engine.trace_count  # one batched trace
     assert traces_after_first == 1
     # same batch size + same bucket => the compilation is reused (the jit
     # cache keys on the stacked shapes, which include the batch dim)
     grid2 = [_a2a_workload(s) for s in ("rectangular", "l_shape", "row")]
-    engine.run_batch(grid2, seeds=[4, 5, 6], horizon=5000)
+    engine.run_grid(grid2, seeds=[4], horizon=5000)
     assert engine.device_calls == 2
     assert engine.trace_count == traces_after_first  # compilation reused
 
@@ -117,9 +117,14 @@ def test_shape_bucket_rounds_up_to_pow2():
     assert shape_bucket(3, 1, 1) == (8, 4, 1)
 
 
-# ------------------------------------------------------------------- facade
+# --------------------------------------------------------------- seed pins
+def _simulate(wl, mode, seed, horizon):
+    engine = get_engine(SMALL, mode=mode, num_pools=wl.num_pools)
+    return engine.run(wl, seed=seed, horizon=horizon)
+
+
 def test_facade_simulate_unchanged_vs_seed():
-    """Regression: simulate() must reproduce the recorded outputs of the
+    """Regression: ``run`` must reproduce the recorded outputs of the
     seed (pre-engine) simulator for a small HyperX(n=4, q=2) case.
 
     Recorded under jax's default ``jax_threefry_partitionable=True``
@@ -127,32 +132,43 @@ def test_facade_simulate_unchanged_vs_seed():
     part = allocate_partition("row", SMALL, 0)
     wl = tr.compose_workload(SMALL, [(tr.all_to_all(16), part)])
 
-    r = simulate(SMALL, wl, mode="omniwar", seed=0, horizon=5000)
+    r = _simulate(wl, mode="omniwar", seed=0, horizon=5000)
     assert (r.makespan, r.delivered, r.injected) == (31, 240, 240)
     assert r.makespan_cycles == 496
     assert r.avg_latency == pytest.approx(6.9625)
     assert r.avg_hops == pytest.approx(1.15)
     assert r.completed
 
-    r = simulate(SMALL, wl, mode="min", seed=0, horizon=5000)
+    r = _simulate(wl, mode="min", seed=0, horizon=5000)
     assert (r.makespan, r.delivered, r.injected) == (37, 240, 240)
     assert r.avg_latency == pytest.approx(9.920833333333333)
     assert r.avg_hops == pytest.approx(0.8)
 
     part2 = allocate_partition("diagonal", SMALL, 0)
     wl2 = tr.compose_workload(SMALL, [(tr.uniform(16, packets=8), part2)])
-    r = simulate(SMALL, wl2, mode="omniwar", seed=3, horizon=4000)
+    r = _simulate(wl2, mode="omniwar", seed=3, horizon=4000)
     assert (r.makespan, r.delivered, r.injected) == (17, 128, 128)
     assert r.avg_latency == pytest.approx(3.1015625)
     assert r.avg_hops == pytest.approx(1.40625)
 
 
-def test_facade_build_simulator_debug_hook():
-    wl = _a2a_workload("row")
-    run = build_simulator(SMALL, wl, horizon=5000)
-    final, d, i, qs = run.debug(seed=0, steps=64, stride=16)
+def test_step_scan_injects_within_64_cycles():
+    """The cycle kernel stands alone: a scan of ``build_step`` from
+    ``init_state`` injects and conserves packets cycle by cycle."""
+    engine = SimEngine(SMALL, mode="omniwar")
+    wt = engine.prepare(_a2a_workload("row")).tables
+    step = build_step(engine.static)
+
+    def body(state, _):
+        s2 = step(state, wt)
+        return s2, (s2.n_delivered, s2.n_injected, s2.qlen.sum())
+
+    state = init_state(engine.static, wt, 0)
+    _, (d, i, qs) = jax.lax.scan(body, state, None, length=64)
+    d, i, qs = (np.asarray(x)[::16] for x in (d, i, qs))
     assert len(d) == len(i) == len(qs) == 4
     assert int(i[-1]) > 0  # packets were injected within 64 cycles
+    assert (i >= d + qs).all()  # in flight or ejected, never lost
 
 
 def test_engine_rejects_pool_mismatch():

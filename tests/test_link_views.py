@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, build_step, init_state
 from repro.core.engine.step import LinkViews, first_min, pick

@@ -4,8 +4,8 @@ The load-bearing pins:
 
   * **telemetry neutrality** — a disabled ``TelemetrySpec`` (the default)
     produces bitwise-identical ``SimResult`` values AND identical compile
-    counts to the pre-telemetry engine, across ``run_batch_seeds`` and
-    ``run_grid``, on every registered routing policy;
+    counts to the pre-telemetry engine, across ``run`` and ``run_grid``,
+    on every registered routing policy;
   * enabled telemetry leaves the physics untouched (results still equal
     the reference bitwise) and its accumulators satisfy conservation
     invariants (injected = delivered = latency-histogram mass);
@@ -24,9 +24,9 @@ import re
 import numpy as np
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
-from repro.core.engine import SimEngine, get_engine
+from repro.core.engine import SimEngine, get_engine, stack_tables
 from repro.core.hyperx import HyperX
 from repro.obs import TelemetrySpec
 from repro.obs import report as obs_report
@@ -52,13 +52,14 @@ def test_telemetry_off_bitwise_and_compile_neutral(mode):
     wls = [_a2a(s) for s in ("row", "diagonal")]
     seeds = (0, 3)
 
-    ref_bs = base.run_batch_seeds(wls, seeds=seeds, horizon=4000)
-    assert off.run_batch_seeds(wls, seeds=seeds, horizon=4000) == ref_bs
+    ref_run = base.run(wls[1], seed=3, horizon=4000)
+    assert off.run(wls[1], seed=3, horizon=4000) == ref_run
     ref_grid = base.run_grid(wls, seeds=seeds, horizon=4000)
     assert off.run_grid(wls, seeds=seeds, horizon=4000) == ref_grid
     assert off.trace_count == base.trace_count
     assert off.device_calls == base.device_calls
-    for per_seed in ref_bs + ref_grid:
+    assert ref_run.telemetry is None
+    for per_seed in ref_grid:
         for r in per_seed:
             assert r.telemetry is None
 
@@ -234,12 +235,13 @@ def test_cycle_stages_name_every_loop_body_op(kernel):
     ``route`` and ``arbitrate`` for the fused kernel, and nothing else."""
     engine = SimEngine(SMALL, mode="omniwar", kernel=kernel)
     prep = engine.prepare(_a2a("row"))
-    text = engine._run1.lower(prep.tables, np.int32(0), np.int32(8)).as_text(
-        debug_info=True)
+    text = engine._runNS.lower(  # the 1 x 1 grid: what ``run`` dispatches
+        stack_tables([prep.tables]), np.zeros(1, np.int32), np.int32(8)
+    ).as_text(debug_info=True)
     body = [n for n in re.findall(r'loc\("([^"]*)"', text)
-            if n.startswith("jit(core)/while/body/")]
+            if n.startswith("jit(core)/vmap(vmap())/while/body/")]
     assert body
-    seen = {n.split("/")[3] for n in body}
+    seen = {n.split("/")[4] for n in body}
     want = set(STAGES)
     if kernel == "pallas":
         want = want - {"route", "arbitrate"} | {"route_arbitrate"}

@@ -9,7 +9,7 @@ image may not ship hypothesis; the property must still be exercised).
 import numpy as np
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, pack, pack_dtype
 from repro.core.engine.tables import build_static_tables

@@ -27,7 +27,7 @@ def _bench(device_s_by_grid, rev="test"):
     return {
         "schema": perf.SCHEMA, "rev": rev, "quick": True, "backend": "cpu",
         "devices": 1, "jax": "x", "arb": "lax", "kernel": "lax",
-        "chunk": 1, "canon": False,
+        "chunk": 1,
         "grids": {
             g: {"lanes": 4, "buckets": 1, "traces": 1, "lane_backend": "vmap",
                 "compile_s": 1.0, "device_s": d, "cycles": 1000,

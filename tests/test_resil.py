@@ -1,6 +1,6 @@
 """Resilience subsystem tests (engine side): epoch-schedule lowering,
 the E=1 bit-identity + trace-count pins vs the static fault path (across
-``run``, ``run_batch_seeds`` AND ``run_grid``, all routing policies),
+``run`` AND ``run_grid``, all routing policies),
 dynamic mid-flight mask flips, fault edge cases (fully-dead switch, dead
 self-ports), telemetry fault counters, and the packet-conservation
 property under arbitrary epoch schedules."""
@@ -13,7 +13,7 @@ try:  # optional test extra (pip install -e .[test]); property tests need it
 except ImportError:  # pragma: no cover - exercised only without hypothesis
     given = settings = hst = None
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine
 from repro.core.hyperx import HyperX
@@ -114,10 +114,10 @@ def test_one_epoch_schedule_bit_identical_to_static_path(mode):
 
 
 @pytest.mark.parametrize("mode", POLICIES)
-def test_e1_pin_run_batch_seeds_and_run_grid(mode):
-    """The E=1 pin holds through both batch dispatchers: static-mask and
-    1-epoch-schedule workloads land in one bucket, one trace, and produce
-    bit-identical grids."""
+def test_e1_pin_run_grid_and_run(mode):
+    """The E=1 pin holds through the grid and the single run: static-mask
+    and 1-epoch-schedule workloads land in one bucket, one trace, and
+    produce bit-identical results."""
     engine = SimEngine(SMALL, mode=mode)
     mask = fail_links(SMALL, [(0, 1)])
     wls = [
@@ -125,13 +125,13 @@ def test_e1_pin_run_batch_seeds_and_run_grid(mode):
         _a2a(schedule=static_schedule(SMALL, mask)),
     ]
     seeds = (0, 3)
-    bs = engine.run_batch_seeds(wls, seeds=seeds, horizon=4000)
+    grid = engine.run_grid(wls, seeds=seeds, horizon=4000)
     assert engine.trace_count == 1
     assert engine.device_calls == 1
-    grid = engine.run_grid(wls, seeds=seeds, horizon=4000)
-    assert grid == bs                    # grid == batch_seeds, bitwise
-    assert bs[1] == bs[0]                # schedule lane == static lane
-    assert engine.trace_count == 1       # no re-trace across dispatchers
+    assert grid[1] == grid[0]            # schedule lane == static lane
+    assert engine.run_grid(wls, seeds=seeds, horizon=4000) == grid
+    assert engine.trace_count == 1       # no re-trace for a repeat grid
+    assert engine.run(wls[1], seed=3, horizon=4000) == grid[0][1]
 
 
 def test_unscheduled_workload_tables_stay_single_epoch():

@@ -11,7 +11,7 @@ try:  # optional test extra (pip install -e .[test]); property tests need it
 except ImportError:  # pragma: no cover - exercised only without hypothesis
     given = settings = hst = None
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, get_engine, make_workload_tables
 from repro.core.hyperx import HyperX
@@ -250,7 +250,8 @@ def test_val_ugal_deliver_and_conserve(mode, mask_name):
         assert is_connected(SMALL, mask)
     eng = get_engine(SMALL, mode=mode)
     wls = [_a2a_workload(s, link_ok=mask) for s in ("row", "diagonal")]
-    for res in eng.run_batch(wls, seeds=[0, 1], horizon=20_000):
+    grid = eng.run_grid(wls, seeds=[0, 1], horizon=20_000)
+    for res in (grid[0][0], grid[1][1]):  # workload i with seed i
         assert res.completed
         assert res.delivered == 240          # == wl.target_packets
         assert res.injected == res.delivered  # no duplication, no loss
@@ -287,6 +288,20 @@ def test_min_mode_fault_escalation_actually_deroutes():
     assert faulty.max_hops > healthy.max_hops  # escalated deroutes happened
 
 
+def test_val_escape_does_not_bounce_between_cut_switches():
+    """Regression: switch 11 keeps one of its three dimension-0 links.  A
+    VAL packet bound for it from switch 3 must escape through switch 7,
+    whose link to 11 is healthy, not to switch 15, which lost its own: a
+    3 <-> 15 bounce spent the whole deroute budget and stranded it."""
+    eng = get_engine(SMALL, mode="val")
+    mask = fail_links(SMALL, [(11, 15), (3, 11)])
+    res = eng.run(_a2a_workload("l_shape", link_ok=mask), seed=78,
+                  horizon=20_000)
+    assert res.completed
+    assert res.delivered == res.injected == 240
+    assert res.max_hops < eng.static.V
+
+
 if hst is not None:
     @given(
         hst.sampled_from(["val", "ugal"]),
@@ -321,7 +336,7 @@ else:
 
 # ------------------------------------------------ compile economics pins
 def test_routing_fault_grid_one_compile_per_bucket():
-    """A routing x strategy x fault x seed grid through run_batch_seeds is
+    """A routing x strategy x fault x seed grid through run_grid is
     ONE trace and ONE device call per shape bucket: fault masks and
     intermediate pools are workload *data*, not compile keys."""
     engine = SimEngine(SMALL, mode="ugal")
@@ -330,7 +345,7 @@ def test_routing_fault_grid_one_compile_per_bucket():
         _a2a_workload(s, link_ok=m)
         for s in ("row", "diagonal") for m in masks
     ]
-    grid = engine.run_batch_seeds(wls, seeds=(0, 1), horizon=20_000)
+    grid = engine.run_grid(wls, seeds=(0, 1), horizon=20_000)
     assert engine.trace_count == 1
     assert engine.device_calls == 1
     assert all(r.completed for per_seed in grid for r in per_seed)

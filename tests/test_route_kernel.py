@@ -15,7 +15,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, make_fused_router
 from repro.core.engine.tables import build_static_tables
@@ -60,8 +60,8 @@ def test_fused_kernel_bit_identical_batched():
     wls = [_a2a_workload(s) for s in ("row", "diagonal", "full_spread")]
     ref = SimEngine(SMALL, mode="omniwar")
     fused = SimEngine(SMALL, mode="omniwar", kernel="pallas")
-    assert fused.run_batch_seeds(wls, seeds=(0, 7), horizon=HORIZON) == \
-        ref.run_batch_seeds(wls, seeds=(0, 7), horizon=HORIZON)
+    assert fused.run_grid(wls, seeds=(0, 7), horizon=HORIZON) == \
+        ref.run_grid(wls, seeds=(0, 7), horizon=HORIZON)
 
 
 def test_fused_kernel_bit_identical_with_telemetry():

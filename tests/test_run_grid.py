@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, default_lane_backend
 from repro.core.hyperx import HyperX
@@ -28,14 +28,15 @@ def _uniform_workload(strategy: str):
     return tr.compose_workload(SMALL, [(tr.uniform(4, packets=4), part)])
 
 
-def test_run_grid_matches_run_batch_seeds_bitwise():
+def test_run_grid_matches_solo_run_bitwise():
     """On one device run_grid IS the nested-vmap cross product — results
-    must be equal field-for-field, including with duplicate seeds."""
+    must equal solo runs field-for-field, including with duplicate seeds."""
     engine = SimEngine(SMALL, mode="omniwar")
     wls = [_a2a_workload(s) for s in ("row", "diagonal", "full_spread")]
     seeds = (0, 7, 7)  # duplicate seed: lane indexing must not collapse it
-    assert engine.run_grid(wls, seeds=seeds, horizon=5000) == \
-        engine.run_batch_seeds(wls, seeds=seeds, horizon=5000)
+    assert engine.run_grid(wls, seeds=seeds, horizon=5000) == [
+        [engine.run(wl, seed=s, horizon=5000) for s in seeds] for wl in wls
+    ]
     assert engine.lane_backend == "vmap"
 
 
@@ -80,7 +81,7 @@ def test_lane_backend_reported_at_construction():
 _SHARDED_SCRIPT = """
 import json
 import jax
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine
 from repro.core.hyperx import HyperX

@@ -3,7 +3,7 @@
 import numpy as np
 import pytest
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition, machine_partitions
 from repro.core.hyperx import HyperX
 

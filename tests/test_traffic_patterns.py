@@ -14,7 +14,7 @@ try:  # optional test extra (pip install -e .[test]); property tests need it
 except ImportError:  # pragma: no cover - exercised only without hypothesis
     given = settings = hst = None
 
-from repro.core import traffic as tr
+from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, get_engine
 from repro.core.engine.workload_tables import shape_bucket
@@ -439,7 +439,7 @@ def test_scenario_seed_derivation():
 # ------------------------------------------------ compile economics pin
 def test_pattern_grid_one_compile_per_bucket():
     """A pattern x strategy x seed grid over the NEW patterns through
-    run_batch_seeds costs ONE trace and ONE device call per shape
+    run_grid costs ONE trace and ONE device call per shape
     bucket: pattern tables are workload *data*, not compile keys."""
     engine = SimEngine(SMALL, mode="omniwar")
     patterns = ("transpose", "tornado", "shuffle", "incast", "stencil_3d")
@@ -451,7 +451,7 @@ def test_pattern_grid_one_compile_per_bucket():
     ]
     buckets = {shape_bucket(wl.R, wl.T, wl.maxd) for wl in wls}
     assert len(buckets) < len(wls)  # the axis genuinely shares buckets
-    grid = engine.run_batch_seeds(wls, seeds=(0, 1), horizon=20_000)
+    grid = engine.run_grid(wls, seeds=(0, 1), horizon=20_000)
     assert engine.trace_count == len(buckets)
     assert engine.device_calls == len(buckets)
     assert all(r.completed for per_seed in grid for r in per_seed)
@@ -459,14 +459,17 @@ def test_pattern_grid_one_compile_per_bucket():
     assert grid[2][1] == engine.run(wls[2], seed=1, horizon=20_000)
 
 
-# ------------------------------------------------------- compat surface
-def test_core_traffic_shim_keeps_seed_surface():
+# ---------------------------------------------------------- seed surface
+def test_traffic_keeps_seed_surface():
+    """The seed's traffic names all live in ``repro.traffic``."""
     for name in ("AppTraffic", "Workload", "compose_workload",
                  "background_noise", "uniform", "all_to_all", "all_reduce",
-                 "stencil", "random_involution", "KERNELS",
-                 "STATIC_PATTERNS", "_empty", "_grid_shape"):
+                 "stencil", "random_involution", "empty_tables",
+                 "grid_shape"):
         assert hasattr(tr, name), name
-    assert set(tr.KERNELS) == {
+    for name in ("KERNELS", "STATIC_PATTERNS"):
+        assert hasattr(tr.patterns, name), name
+    assert set(tr.patterns.KERNELS) == {
         "all_to_all", "all_reduce", "stencil_von_neumann", "stencil_moore",
         "random_involution",
     }
