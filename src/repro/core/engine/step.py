@@ -48,7 +48,8 @@ U32 = jnp.uint32
 class SimState(NamedTuple):
     t: jnp.ndarray            # () int32 — current packet-time
     key: jnp.ndarray          # PRNG key
-    # queue field arrays, flat (NQ * CAP,)
+    # queue field arrays, flat (NQ * CAP,) and slot-major: slot c of queue
+    # qi sits at c * NQ + qi, so row c (reshape(CAP, NQ)) is one dense vector
     f_dst: jnp.ndarray        # destination endpoint id
     f_der: jnp.ndarray        # deroutes left
     f_hop: jnp.ndarray        # hops taken
@@ -188,6 +189,18 @@ def pick(x, idx):
     return jnp.where(hot, x, jnp.zeros((), x.dtype)).sum(axis=1)
 
 
+def ring_front(f, qhead):
+    """Every queue's front packet field: ``f.reshape(CAP, NQ)[qhead[i], i]``
+    for a slot-major queue field ``f`` (``(CAP * NQ,)``) and ring heads
+    ``qhead`` (``(NQ,)``).  ``CAP - 1`` selects over the dense slot rows,
+    where a per-queue gather would read one element a queue."""
+    rows = f.reshape(-1, qhead.shape[0])
+    out = rows[0]
+    for c in range(1, rows.shape[0]):
+        out = jnp.where(qhead == c, rows[c], out)
+    return out
+
+
 def first_min(x):
     """Row minimum of an ``(H, W)`` array and the first lane holding it
     (``jnp.argmin``'s tie rule)."""
@@ -294,10 +307,9 @@ def build_step(
 
             # the packet at the front of every queue
             exists = qlen > 0                                   # (H,)
-            slot = jnp.arange(H, dtype=I32) * CAP + qhead
-            dst = state.f_dst[slot]
-            der = state.f_der[slot]
-            hop = state.f_hop[slot]
+            dst = ring_front(state.f_dst, qhead)
+            der = ring_front(state.f_der, qhead)
+            hop = ring_front(state.f_hop, qhead)
             dsw = dst // conc
             dof = dst % conc
 
@@ -307,7 +319,7 @@ def build_step(
             # Valiant phase 1 routes toward the packet's intermediate switch;
             # reaching it (or the final destination early) flips to phase 2.
             if use_imd:
-                imd = state.f_imd[slot]
+                imd = ring_front(state.f_imd, qhead)
                 in_phase1 = (imd < S) & (imd != cur) & ~at_dst
                 route_dsw = jnp.where(in_phase1, imd, dsw)
             else:
@@ -469,8 +481,10 @@ def build_step(
         with jax.named_scope("deliver"):
             # deliveries (ejection winners)
             eject = won & at_dst
-            rank = state.f_rank[slot]
-            pstep = state.f_step[slot]
+            # the front packets, read at the ring heads before this dequeue
+            rank = ring_front(state.f_rank, state.qhead)
+            pstep = ring_front(state.f_step, state.qhead)
+            birth = ring_front(state.f_birth, state.qhead)
             src_finite = wt.finite[rank]
             # sender-side accounting row (infinite sources -> trash row R)
             send_row = jnp.where(src_finite, rank, R)
@@ -485,7 +499,7 @@ def build_step(
                 jnp.where(eject, recv_row * T + pstep, OOB_RT)
             ].add(1, mode="drop")
             tgt_del = eject & src_finite
-            lat_pkt = (t - state.f_birth[slot]).astype(jnp.float32)
+            lat_pkt = (t - birth).astype(jnp.float32)
             lat_add = jnp.sum(jnp.where(tgt_del, lat_pkt, 0.0))
             lat_sum = state.lat_sum + lat_add
             hop_sum = state.hop_sum + jnp.sum(jnp.where(tgt_del, hop, 0))
@@ -509,13 +523,13 @@ def build_step(
                 state.qhead[tgt_qi] + qlen[tgt_qi]
                 + jnp.where(won2, arr1_tgt, 0)
             ) % CAP
-            tgt_flat = jnp.where(net, tgt_qi * CAP + tgt_slot, OOB)
+            tgt_flat = jnp.where(net, tgt_slot * NQ + tgt_qi, OOB)
             f_dst = state.f_dst.at[tgt_flat].set(dst, mode="drop")
             f_der = state.f_der.at[tgt_flat].set(der - (~best_min), mode="drop")
             f_hop = state.f_hop.at[tgt_flat].set(hop + 1, mode="drop")
             f_rank = state.f_rank.at[tgt_flat].set(rank, mode="drop")
             f_step = state.f_step.at[tgt_flat].set(pstep, mode="drop")
-            f_birth = state.f_birth.at[tgt_flat].set(state.f_birth[slot], mode="drop")
+            f_birth = state.f_birth.at[tgt_flat].set(birth, mode="drop")
             if use_imd:
                 # a packet leaving its intermediate switch enters phase 2
                 f_imd = state.f_imd.at[tgt_flat].set(
@@ -573,7 +587,7 @@ def build_step(
             d_ep = wt.rank_ep[d_rank].astype(I32)
 
             inj_flat = jnp.where(
-                do_inj, inj_qi * CAP + (state.qhead[inj_qi] + qlen[inj_qi]) % CAP,
+                do_inj, (state.qhead[inj_qi] + qlen[inj_qi]) % CAP * NQ + inj_qi,
                 OOB,
             )
             f_dst = f_dst.at[inj_flat].set(d_ep, mode="drop")

@@ -5,14 +5,17 @@ arbitration block.
 one row per switch or per link, broadcast over the switch's heads, in
 place of ``(H, q*n)`` gathers; ``step.pick`` reads a row's value at the
 head's chosen port with a one-hot reduce, in place of a per-head gather,
-and ``step.first_min`` finds that port as ``jnp.argmin`` would.
-Pinned here: each view and each pick equals the gather it replaced,
+and ``step.first_min`` finds that port as ``jnp.argmin`` would;
+``step.ring_front`` reads every queue's front packet from the slot-major
+queue fields with a select over the ring slots, in place of a per-queue
+gather.  Pinned here: each view, pick and front read equals the gather it
+replaced,
 computed from the tables with the original index formulas, for every head
 (empty heads with stale hop counts, rows with no legal port and rows with
 tied minima included), packed and unpacked tables; and the default
 engine's step lowers with no gather of ``H * q*n`` results and none out of
-an ``(H, q*n)`` or ``(H, OUT)`` row or of the output tokens, so the
-per-head gathers cannot silently return.
+an ``(H, q*n)`` or ``(H, OUT)`` row, of the output tokens or of a queue
+field, so the per-head gathers cannot silently return.
 """
 
 import re
@@ -25,7 +28,7 @@ import pytest
 from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, build_step, init_state
-from repro.core.engine.step import LinkViews, first_min, pick
+from repro.core.engine.step import LinkViews, first_min, pick, ring_front
 from repro.core.engine.tables import build_static_tables
 from repro.core.hyperx import HyperX
 from repro.route import random_link_faults
@@ -144,6 +147,22 @@ def test_port_picks_equal_the_gathers_they_replace(name, topo, mode, pools,
     assert ((cost == lo).sum(axis=1) > 1)[lo[:, 0] < big].any()
 
 
+@pytest.mark.parametrize("dtype", ["int32", "bool"])
+@pytest.mark.parametrize("cap", [1, 2, 8])
+def test_ring_front_equals_the_gather_it_replaces(cap, dtype):
+    nq = 384
+    rng = np.random.default_rng(18)
+    f = (rng.integers(-5, 1 << 20, cap * nq) if dtype == "int32"
+         else rng.random(cap * nq) < 0.5).astype(dtype)
+    qhead = rng.integers(0, cap, nq).astype(np.int32)
+    got = ring_front(jnp.asarray(f), jnp.asarray(qhead))
+    assert got.dtype == f.dtype and got.shape == (nq,)
+    np.testing.assert_array_equal(
+        got, f.reshape(cap, nq)[qhead, np.arange(nq)])
+    # every slot was drawn as a head
+    assert len(set(qhead.tolist())) == cap
+
+
 def _gathers(hlo: str) -> list:
     """``(operand dims, operand element type, result dims)`` of every
     gather."""
@@ -198,3 +217,21 @@ def test_default_step_lowers_without_per_head_port_gathers():
     sizes = _gather_result_sizes(hlo)
     assert sizes, "no gather found: the guard reads nothing"
     assert st.H * st.q * st.n not in sizes
+
+
+@pytest.mark.parametrize("mode", ["omniwar", "val"])
+def test_default_step_reads_queue_fronts_without_gathers(mode):
+    topo = HyperX(n=8, q=2)
+    part = allocate_partition("row", topo, 0)
+    wl = tr.compose_workload(topo, [(tr.all_to_all(16), part)])
+    engine = SimEngine(topo, mode=mode, num_pools=wl.num_pools)
+    st = engine.static
+    wt = engine.prepare(wl).tables
+    state = init_state(st, wt, 0)
+    assert state.f_dst.shape == (st.NQ * st.CAP,)
+    assert state.f_imd.shape == ((st.NQ * st.CAP,) if mode == "val" else (1,))
+    hlo = jax.jit(build_step(st)).lower(state, wt).as_text()
+    gathers = _gathers(hlo)
+    assert gathers, "no gather found: the guard reads nothing"
+    assert not [g for g in gathers
+                if int(np.prod(g[0])) == st.NQ * st.CAP]
