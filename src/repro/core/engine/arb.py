@@ -2,8 +2,10 @@
 
 One arbitration round resolves, for every queue head in the machine, the
 request it posted against one output port of its own switch: per output,
-the head carrying the smallest packed key (15 random bits << 17 | global
-head index — unique, so ties are impossible) wins.  The step kernel runs
+the head carrying the smallest packed key (random bits << head_bits |
+global head index, with ``head_bits`` 17 below 2**17 heads a lane and the
+least width that holds the heads above; ``tables.head_field_bits`` — unique,
+so ties are impossible) wins.  The step kernel runs
 two such rounds per cycle (separable allocation with the paper's 2x
 internal speedup); this module provides the round primitive
 

@@ -14,7 +14,8 @@ program instance per switch.  Three structural facts make that possible:
     the round-2 room check ``qlen + arr1 < CAP`` needs was produced by
     switch ``s`` itself.  ``arr1`` never has to leave VMEM: it is a
     per-switch histogram over (port, pool, VC) signatures;
-  * packed arbitration keys (``rand15 << 17 | global head index``) are
+  * packed arbitration keys (``random bits << head_bits | global head
+    index``, built by the step kernel: ``tables.head_field_bits``) are
     globally unique, so masked integer min is tie-free and the kernel is
     **bit-exact** against the lax reference on every backend.
 

@@ -284,6 +284,12 @@ class SimEngine:
         IN * P * V, the length of every per-head array of the step."""
         return self.static.H
 
+    @property
+    def head_bits(self) -> int:
+        """Width of the packed arbitration key's head field: 17 below 2**17
+        heads a lane, else the least that holds them (``tables.py``)."""
+        return self.static.head_bits
+
     # ------------------------------------------------------------ running
     def run(
         self,
@@ -405,6 +411,7 @@ class SimEngine:
             padded_lanes=int(np.prod(dims)), bucket=str(bucket),
             new_key=new_key, backend=self.lane_backend,
             heads=self.heads_per_lane, switches=self.static.S,
+            head_bits=self.head_bits, net_ports=self.static.q * self.static.n,
         ):
             outs = jax.block_until_ready(
                 runner(tables, seeds, jnp.int32(horizon))

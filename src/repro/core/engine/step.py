@@ -36,7 +36,7 @@ import numpy as np
 
 from repro.core.engine.arb import make_arbiter
 from repro.core.engine.route_kernel import make_fused_router
-from repro.core.engine.tables import HEAD_BITS, StaticTables, onward_index
+from repro.core.engine.tables import StaticTables, onward_index
 from repro.core.engine.workload_tables import WorkloadTables
 from repro.obs.probes import TelemetrySpec, TelemetryState
 from repro.route import get_policy
@@ -209,6 +209,14 @@ def first_min(x):
     return lo, jnp.where(x == lo[:, None], lane, x.shape[1]).min(axis=1)
 
 
+def packed_keys(k_arb, H: int, head_bits: int) -> jax.Array:
+    """One cycle's arbitration keys: a random draw above each head's index
+    in the low ``head_bits`` (``tables.head_field_bits``), unique, so the
+    smallest key wins an output without ties."""
+    arb_key = jax.random.bits(k_arb, (H,), dtype=U32) >> head_bits
+    return (arb_key << head_bits) | jnp.arange(H, dtype=U32)
+
+
 def build_step(
     st: StaticTables,
     telemetry: TelemetrySpec | None = None,
@@ -330,8 +338,7 @@ def build_step(
             busy_dec = jnp.maximum(state.busy - 1, 0)           # link served 1 pkt
             vcn = jnp.minimum(hop + 1, V - 1)                   # (H,) next VC
             jitter = jax.random.randint(k_jit, (H, q * n), 0, 8, dtype=I32)
-            arb_key = jax.random.bits(k_arb, (H,), dtype=U32) >> HEAD_BITS
-            packed = (arb_key << HEAD_BITS) | jnp.arange(H, dtype=U32)
+            packed = packed_keys(k_arb, H, st.head_bits)
 
         def route_arbitrate_lax():
             with jax.named_scope("route"):

@@ -31,9 +31,30 @@ from repro.core.engine.packing import pack
 from repro.core.hyperx import HyperX
 from repro.route import get_policy, neighbor_tables, port_layout
 
-# low bits of the packed arbitration key that hold the head index; the
-# random draw fills the 32 - HEAD_BITS above them (step.py)
-HEAD_BITS = 17
+# The packed arbitration key (step.py) is one uint32: a random draw in the
+# high bits, the head index in the low ``head_bits``, so keys are unique and
+# the smallest draw wins an output.  The head field is MIN_HEAD_BITS wide
+# for every machine under 2**17 heads a lane (their programs and random
+# streams are those of a fixed 17-bit field) and just wide enough above
+# that, at the cost of random bits.  Below MIN_RANDOM_BITS random bits,
+# equal draws at the minimum grow common and each goes to the lower head
+# index, so arbitration would favour low-numbered queues: such machines are
+# refused (H < 2**24).
+MIN_HEAD_BITS = 17
+MIN_RANDOM_BITS = 8
+
+
+def head_field_bits(H: int) -> int:
+    """Width of the packed key's head field for ``H`` heads a lane: the
+    least width >= MIN_HEAD_BITS that holds every index below ``H`` (so no
+    key reaches 2**32 - 1, the arbiters' "no request")."""
+    hb = max(MIN_HEAD_BITS, H.bit_length())
+    if 32 - hb < MIN_RANDOM_BITS:
+        raise ValueError(
+            f"{H} queue heads per lane leave {32 - hb} random bits in the "
+            f"packed arbitration key (random bits << {hb} | head), fewer "
+            f"than {MIN_RANDOM_BITS}")
+    return hb
 
 I32 = jnp.int32
 
@@ -63,6 +84,7 @@ class StaticTables(NamedTuple):
     V: int
     NQ: int
     H: int
+    head_bits: int    # head field of the packed arbitration key
     CAP: int
     m: int            # deroute budget
     PEN: int          # deroute penalty on the cost scale
@@ -118,11 +140,7 @@ def build_static_tables(
     V = policy.vc_budget(q, m)  # hop-indexed VCs (deadlock freedom)
     NQ = S * IN * P * V
     H = NQ                     # one potential head per queue
-    if H >= 1 << HEAD_BITS:
-        raise ValueError(
-            f"{H} queue heads per lane do not fit the {HEAD_BITS}-bit head "
-            f"field of the packed arbitration key (random bits << "
-            f"{HEAD_BITS} | head): {topo}, mode={mode!r}, V={V}, P={P}")
+    head_bits = head_field_bits(H)
 
     coords_np = topo.all_switch_coords()                       # (S, q)
     nbr, in_port_at_nb = neighbor_tables(coords_np, n, q)
@@ -149,7 +167,7 @@ def build_static_tables(
 
     return StaticTables(
         n=n, q=q, conc=conc, S=S, E=E, IN=IN, OUT=OUT, P=P, V=V,
-        NQ=NQ, H=H, CAP=cap, m=m,
+        NQ=NQ, H=H, head_bits=head_bits, CAP=cap, m=m,
         PEN=penalty_packets * 8,  # cost scale: occupancy*8 + jitter(3 bits)
         mode=mode, arb=arb, kernel=kernel,
         coords=lower(coords_np, n - 1),

@@ -104,16 +104,23 @@ def test_engine_pallas_arb_bit_identical():
 
 
 def test_packed_key_head_field_guard():
-    """Engine tables refuse a machine whose heads per lane overflow the
-    17-bit head field of the packed key (the index would spill into the
-    random bits and arbitration would go wrong without an error)."""
-    from repro.core.engine.tables import HEAD_BITS, build_static_tables
+    """The packed key's head field holds every head of a lane: 17 bits
+    under 2**17 heads, the least width that holds them above (fewer random
+    bits), and the engine refuses a machine that would leave fewer than
+    ``MIN_RANDOM_BITS`` random bits (H >= 2**24)."""
+    from repro.core.engine.tables import (MIN_RANDOM_BITS,
+                                          build_static_tables,
+                                          head_field_bits)
 
     topo = HyperX(n=8, q=3)
     st = build_static_tables(topo, mode="omniwar")
-    assert st.H == 512 * 32 * 7 < 1 << HEAD_BITS
-    for mode in ("val", "ugal"):
-        with pytest.raises(ValueError, match="17-bit head field"):
-            build_static_tables(topo, mode=mode)
-    with pytest.raises(ValueError, match="17-bit head field"):
-        SimEngine(topo, mode="omniwar", max_deroutes=6)  # V = 10
+    assert st.H == 512 * 32 * 7 < 1 << 17 and st.head_bits == 17
+    for mode in ("val", "ugal"):  # 13 VCs: 212,992 heads
+        st = build_static_tables(topo, mode=mode)
+        assert st.H == 512 * 32 * 13 and st.head_bits == 18
+    assert MIN_RANDOM_BITS == 8
+    assert head_field_bits((1 << 24) - 1) == 24
+    with pytest.raises(ValueError, match="fewer than 8"):
+        head_field_bits(1 << 24)
+    with pytest.raises(ValueError, match="fewer than 8"):
+        SimEngine(topo, mode="omniwar", max_deroutes=1021)  # V = 1,025

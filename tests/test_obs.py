@@ -198,7 +198,8 @@ def test_engine_dispatch_spans(tmp_path):
 def test_placement_and_machine_size_in_the_trace(tmp_path):
     """``alloc.place`` wraps each strategy-name placement of a scenario
     (strategy, q, ranks, distinct switches touched); ``engine.dispatch``
-    carries the heads per lane and the switches."""
+    carries the heads per lane, the switches, the packed key's head field
+    and the network port slots (q * n) of each head's row."""
     from repro.traffic import AppSpec, ScenarioSpec, build_workload
 
     d = str(tmp_path / "trace")
@@ -222,6 +223,8 @@ def test_placement_and_machine_size_in_the_trace(tmp_path):
     dispatch, = [e for e in events if e.get("name") == "engine.dispatch"]
     assert dispatch["heads"] == engine.heads_per_lane == 64 * 16 * 7
     assert dispatch["switches"] == 64
+    assert dispatch["head_bits"] == engine.head_bits == 17
+    assert dispatch["net_ports"] == 12
 
 
 STAGES = ("heads", "route", "arbitrate", "dequeue", "deliver", "move",
