@@ -53,8 +53,12 @@ class SimState(NamedTuple):
     f_dst: jnp.ndarray        # destination endpoint id
     f_der: jnp.ndarray        # deroutes left
     f_hop: jnp.ndarray        # hops taken
-    f_rank: jnp.ndarray       # source rank
-    f_step: jnp.ndarray       # source step index
+    f_rank: jnp.ndarray       # sent index: send_row * T + source step, where
+                              # send_row is the source rank, or the trash
+                              # row R for an infinite source
+    f_step: jnp.ndarray       # got index: recv_row * T + source step, where
+                              # recv_row is the destination's rank, or R
+                              # when that is no finite rank
     f_birth: jnp.ndarray      # injection time
     f_imd: jnp.ndarray        # Valiant intermediate switch (S = none);
                               # shape (1,) for policies without intermediates
@@ -488,24 +492,20 @@ def build_step(
         with jax.named_scope("deliver"):
             # deliveries (ejection winners)
             eject = won & at_dst
-            # the front packets, read at the ring heads before this dequeue
-            rank = ring_front(state.f_rank, state.qhead)
-            pstep = ring_front(state.f_step, state.qhead)
+            # the front packets, read at the ring heads before this dequeue;
+            # their accounting rows were resolved when they were injected
+            sent_idx = ring_front(state.f_rank, state.qhead)
+            got_idx = ring_front(state.f_step, state.qhead)
             birth = ring_front(state.f_birth, state.qhead)
-            src_finite = wt.finite[rank]
-            # sender-side accounting row (infinite sources -> trash row R)
-            send_row = jnp.where(src_finite, rank, R)
             OOB_RT = jnp.int32((R + 1) * T + 1)
             sent = state.sent.at[
-                jnp.where(eject, send_row * T + pstep, OOB_RT)
+                jnp.where(eject, sent_idx, OOB_RT)
             ].add(1, mode="drop")
-            drank = wt.ep_rank[dst].astype(I32)
-            drank_ok = (drank >= 0) & wt.finite[jnp.maximum(drank, 0)]
-            recv_row = jnp.where(drank_ok, drank, R)
             got = state.got.at[
-                jnp.where(eject, recv_row * T + pstep, OOB_RT)
+                jnp.where(eject, got_idx, OOB_RT)
             ].add(1, mode="drop")
-            tgt_del = eject & src_finite
+            # infinite sources count in the trash row R
+            tgt_del = eject & (sent_idx < R * T)
             lat_pkt = (t - birth).astype(jnp.float32)
             lat_add = jnp.sum(jnp.where(tgt_del, lat_pkt, 0.0))
             lat_sum = state.lat_sum + lat_add
@@ -534,8 +534,8 @@ def build_step(
             f_dst = state.f_dst.at[tgt_flat].set(dst, mode="drop")
             f_der = state.f_der.at[tgt_flat].set(der - (~best_min), mode="drop")
             f_hop = state.f_hop.at[tgt_flat].set(hop + 1, mode="drop")
-            f_rank = state.f_rank.at[tgt_flat].set(rank, mode="drop")
-            f_step = state.f_step.at[tgt_flat].set(pstep, mode="drop")
+            f_rank = state.f_rank.at[tgt_flat].set(sent_idx, mode="drop")
+            f_step = state.f_step.at[tgt_flat].set(got_idx, mode="drop")
             f_birth = state.f_birth.at[tgt_flat].set(birth, mode="drop")
             if use_imd:
                 # a packet leaving its intermediate switch enters phase 2
@@ -592,6 +592,13 @@ def build_step(
             d_rank = jnp.where(wt.sampled[r_safe, flat_td], d_smp, d_fixed)
             d_rank = jnp.clip(d_rank, 0, R - 1)
             d_ep = wt.rank_ep[d_rank].astype(I32)
+            # the packet's accounting rows, resolved here once an endpoint so
+            # that deliver reads no table per head: infinite sources, and
+            # destinations of no finite rank, count in the trash row R
+            send_row = jnp.where(e_fin, r_safe, R)
+            drank = wt.ep_rank[d_ep].astype(I32)
+            drank_ok = (drank >= 0) & wt.finite[jnp.maximum(drank, 0)]
+            recv_row = jnp.where(drank_ok, drank, R)
 
             inj_flat = jnp.where(
                 do_inj, (state.qhead[inj_qi] + qlen[inj_qi]) % CAP * NQ + inj_qi,
@@ -600,8 +607,8 @@ def build_step(
             f_dst = f_dst.at[inj_flat].set(d_ep, mode="drop")
             f_der = f_der.at[inj_flat].set(jnp.int32(m), mode="drop")
             f_hop = f_hop.at[inj_flat].set(0, mode="drop")
-            f_rank = f_rank.at[inj_flat].set(r_safe, mode="drop")
-            f_step = f_step.at[inj_flat].set(jnp.where(e_fin, e_cs, 0), mode="drop")
+            f_rank = f_rank.at[inj_flat].set(send_row * T + e_cs, mode="drop")
+            f_step = f_step.at[inj_flat].set(recv_row * T + e_cs, mode="drop")
             f_birth = f_birth.at[inj_flat].set(t, mode="drop")
             if use_imd:
                 # Valiant intermediate: one uniform draw per packet from the

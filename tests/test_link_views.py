@@ -15,7 +15,9 @@ computed from the tables with the original index formulas, for every head
 tied minima included), packed and unpacked tables; and the default
 engine's step lowers with no gather of ``H * q*n`` results and none out of
 an ``(H, q*n)`` or ``(H, OUT)`` row, of the output tokens or of a queue
-field, so the per-head gathers cannot silently return.
+field, and with no H-wide read of a per-rank or per-endpoint table (a
+packet's accounting rows ride in its queue fields from injection), so the
+per-head gathers cannot silently return.
 """
 
 import re
@@ -28,7 +30,9 @@ import pytest
 from repro import traffic as tr
 from repro.core.allocation import allocate_partition
 from repro.core.engine import SimEngine, build_step, init_state
-from repro.core.engine.step import LinkViews, first_min, pick, ring_front
+from repro.core.engine.step import (
+    LinkViews, SimState, first_min, pick, ring_front,
+)
 from repro.core.engine.tables import build_static_tables
 from repro.core.hyperx import HyperX
 from repro.route import random_link_faults
@@ -235,3 +239,24 @@ def test_default_step_reads_queue_fronts_without_gathers(mode):
     assert gathers, "no gather found: the guard reads nothing"
     assert not [g for g in gathers
                 if int(np.prod(g[0])) == st.NQ * st.CAP]
+
+
+@pytest.mark.parametrize("mode", ["omniwar", "val"])
+def test_default_step_delivers_without_per_head_table_gathers(mode):
+    # a packet's sent and got rows ride in f_rank and f_step from its
+    # injection; a seventh queue field would add an H-wide scatter-set to
+    # every move, which costs more than the lookups it could save
+    assert [f for f in SimState._fields if f.startswith("f_")] == [
+        "f_dst", "f_der", "f_hop", "f_rank", "f_step", "f_birth", "f_imd"]
+    topo = HyperX(n=8, q=2)
+    part = allocate_partition("row", topo, 0)
+    wl = tr.compose_workload(topo, [(tr.all_to_all(16), part)])
+    engine = SimEngine(topo, mode=mode, num_pools=wl.num_pools)
+    st = engine.static
+    wt = engine.prepare(wl).tables
+    hlo = jax.jit(build_step(st)).lower(init_state(st, wt, 0), wt).as_text()
+    tables = {(wt.R,), (st.E,)}
+    reads = [g for g in _gathers(hlo) if g[0] in tables]
+    # injection reads the per-rank and per-endpoint tables once an endpoint
+    assert [g for g in reads if g[2] == (st.E,)], "the guard reads nothing"
+    assert not [g for g in reads if g[2] == (st.H,)]
